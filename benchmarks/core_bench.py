@@ -80,4 +80,6 @@ def run(label: str | None = None, n_rows: int = 1 << 15,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(label=sys.argv[1] if len(sys.argv) > 1 else None)

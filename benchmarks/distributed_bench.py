@@ -2,7 +2,8 @@
 ``BENCH_core.json`` under ``dist_runs``.
 
 Workload: the shuffle-heavy PigMix shape — join(page_views, users) then
-group-by user — on an 8-way forced-host device mesh.  The users table
+group-by user — on a data mesh over every device of a TPU host, or, as a CPU
+rehearsal, an 8-way forced-host device mesh.  The users table
 scales with the data (n_rows / 8 distinct users) the way PigMix's does.
 Inputs live in the store's distributed layout: the partition-aware
 engine loads them co-partitioned on the demanded keys (one cached host
@@ -32,7 +33,9 @@ configuration, join probe slack on the co-partitioned one, and the
 Pallas scatter tile priced through roofline/analysis.py; the persisted
 table then feeds every arm via ``autotune.choose``.
 
-The sweep runs in a SUBPROCESS that sets
+On a TPU host the sweep runs in this process, on a mesh over
+``jax.devices()``: the chips belong to the process that first touched
+JAX.  On a CPU-only host it runs in a SUBPROCESS that sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before importing
 jax, exactly like tests/test_distributed.py; the parent process (and
 anything else in the same interpreter) keeps its 1-device view.
@@ -57,20 +60,21 @@ HISTORY_PER_LABEL = 5        # the check_bench regression gate needs
 
 
 # ---------------------------------------------------------------------------
-# Child: runs inside the 8-device subprocess
+# Sweep: in-process on a TPU host, in the 8-device subprocess on CPU
 
 
-def _child(n_rows: int, trials: int, out_path: str) -> None:
+def _sweep(n_rows: int, trials: int, n_shards: int) -> dict:
     sys.path.insert(0, os.path.join(_ROOT, "src"))
     import jax
 
     from repro.core import plan as P
     from repro.core.restore import ReStore
+    from repro.launch.mesh import make_data_mesh
     from repro.store.artifacts import ArtifactStore, Catalog
     from repro.workloads import pigmix
 
-    assert len(jax.devices()) >= N_SHARDS, jax.devices()
-    mesh = jax.make_mesh((N_SHARDS,), ("data",))
+    assert len(jax.devices()) >= n_shards, jax.devices()
+    mesh = make_data_mesh(n_shards)
 
     def probe(aggs):
         pv = P.project(P.load("page_views"), ["user", "estimated_revenue"])
@@ -153,12 +157,15 @@ def _child(n_rows: int, trials: int, out_path: str) -> None:
         best = autotune.tune("join_probe", 0, "uint32", "slack",
                              [1, 2, 4], slack_measure, table=table, reps=1)
         slack_measure(best)          # leave the winner in the table
-        # Pallas scatter tile: priced analytically (roofline) — a CPU
-        # host cannot time the real kernel, hardware runs would measure
-        price = autotune.scatter_tile_price(n_rows, N_SHARDS)
-        autotune.tune("partition_scatter", n_rows, "uint32", "tile_n",
-                      [256, 512, 1024, 2048], price,
-                      table=table, price=price, top_k=4, reps=1)
+        # Pallas scatter tile: priced analytically (roofline) on a chip
+        # with published peaks; a CPU host keeps the built-in tile, as
+        # it can neither price nor time the real kernel
+        from repro.roofline.analysis import PEAKS
+        if autotune.device_kind() in PEAKS:
+            price = autotune.scatter_tile_price(n_rows, n_shards)
+            autotune.tune("partition_scatter", n_rows, "uint32", "tile_n",
+                          [1024, 2048, 4096, 8192], price,
+                          table=table, price=price, top_k=4, reps=1)
         table.save(autotune.table_path())
         autotune.get_table(refresh=True)
 
@@ -187,7 +194,7 @@ def _child(n_rows: int, trials: int, out_path: str) -> None:
             close(rs)
 
     rec = {
-        "n_rows": n_rows, "n_shards": N_SHARDS, "trials": trials,
+        "n_rows": n_rows, "n_shards": n_shards, "trials": trials,
         "arms": {"t_single_s": round(med(t_single), 6),
                  "t_mesh_plain_s": round(med(t_mesh), 6),
                  "t_reuse_blind_s": round(med(t_blind), 6),
@@ -201,8 +208,26 @@ def _child(n_rows: int, trials: int, out_path: str) -> None:
             med(t_single) / max(med(t_mesh), 1e-9), 4),
     }
     assert skipped > 0, "partition-aware arm never skipped an exchange"
-    with open(out_path, "w") as f:
-        json.dump(rec, f)
+    return rec
+
+
+def _forced_host_sweep(n_rows: int, trials: int) -> dict:
+    """CPU rehearsal: the sweep in a child with N_SHARDS forced host
+    devices (the flag must be set before the child's JAX starts)."""
+    child_out = tempfile.mktemp(suffix=".json")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_SHARDS}"
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--child", child_out,
+         "--n-rows", str(n_rows), "--trials", str(trials)],
+        env=env, capture_output=True, text=True, timeout=3600)
+    if proc.returncode != 0:
+        raise RuntimeError(f"distributed bench child failed:\n"
+                           f"{proc.stderr[-3000:]}")
+    with open(child_out) as f:
+        rec = json.load(f)
+    os.unlink(child_out)
+    return rec
 
 
 # ---------------------------------------------------------------------------
@@ -218,28 +243,21 @@ def run(label: str | None = None, n_rows: int = 1 << 16,
     n_rows = int(os.environ.get("DIST_BENCH_NROWS", n_rows))
     trials = int(os.environ.get("DIST_BENCH_TRIALS", trials))
 
-    child_out = tempfile.mktemp(suffix=".json")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_SHARDS}"
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--child", child_out,
-         "--n-rows", str(n_rows), "--trials", str(trials)],
-        env=env, capture_output=True, text=True, timeout=3600)
-    if proc.returncode != 0:
-        raise RuntimeError(f"distributed bench child failed:\n"
-                           f"{proc.stderr[-3000:]}")
-    with open(child_out) as f:
-        rec = json.load(f)
-    os.unlink(child_out)
+    import jax
+    if jax.default_backend() == "cpu":
+        rec = _forced_host_sweep(n_rows, trials)
+    else:
+        rec = _sweep(n_rows, trials, len(jax.devices()))
     rec["label"] = label or "run"
 
     a = rec["arms"]
+    mesh = f"dist/mesh{rec['n_shards']}"
     emit("dist/single_device", a["t_single_s"], "plain")
-    emit("dist/mesh8_plain", a["t_mesh_plain_s"],
+    emit(f"{mesh}_plain", a["t_mesh_plain_s"],
          f"vs_single={rec['mesh_vs_single']:.2f}")
-    emit("dist/mesh8_reuse_blind", a["t_reuse_blind_s"],
+    emit(f"{mesh}_reuse_blind", a["t_reuse_blind_s"],
          "warm;monolithic artifact")
-    emit("dist/mesh8_reuse_copart", a["t_reuse_copart_s"],
+    emit(f"{mesh}_reuse_copart", a["t_reuse_copart_s"],
          f"warm;speedup_vs_blind={rec['speedup_copart_vs_blind']:.2f};"
          f"skipped={rec['shuffles_skipped']}")
 
@@ -272,10 +290,14 @@ def main() -> None:
     ap.add_argument("--label", default=None)
     args = ap.parse_args()
     if args.child:
-        _child(args.n_rows, args.trials, args.child)
+        rec = _sweep(args.n_rows, args.trials, N_SHARDS)
+        with open(args.child, "w") as f:
+            json.dump(rec, f)
     else:
         run(label=args.label, n_rows=args.n_rows, trials=args.trials)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

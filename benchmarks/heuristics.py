@@ -43,4 +43,6 @@ def run(n_rows: int = 1 << 14):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -199,4 +199,6 @@ def run(label: str | None = None, out_path: str = OUT):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(label=sys.argv[1] if len(sys.argv) > 1 else None)

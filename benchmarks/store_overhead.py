@@ -29,4 +29,6 @@ def run(n_small: int = 1 << 13, n_large: int = 1 << 15):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

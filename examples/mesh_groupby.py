@@ -9,9 +9,10 @@
      on the consumer's keys, the group-by runs SHUFFLE-FREE — reuse
      skips the exchange, not just the compute.
 
-This script re-executes itself with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the mesh
-exists on a plain CPU machine (set before any jax import, as always).
+On an accelerator host the mesh spans the host's own devices, in this
+one process.  On a CPU-only machine the script re-executes itself with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so an 8-way mesh
+exists there (the flag must be set before JAX starts its backend).
 
 Usage: PYTHONPATH=src python examples/mesh_groupby.py
 """
@@ -29,10 +30,12 @@ def main():
 
     from repro.core import plan as P
     from repro.core.restore import ReStore
+    from repro.launch.mesh import make_data_mesh
     from repro.store.artifacts import ArtifactStore, Catalog
     from repro.workloads import pigmix
 
-    mesh = jax.make_mesh((N_DEVICES,), ("data",))
+    n_dev = min(N_DEVICES, len(jax.devices()))
+    mesh = make_data_mesh(n_dev)
     store = ArtifactStore()
     catalog = Catalog(store)
     pigmix.register_all(catalog, n_rows=1 << 13)
@@ -44,7 +47,7 @@ def main():
         j = P.join(pv, u, ["user"], ["name"])
         return P.PhysicalPlan([P.store(P.groupby(j, ["user"], aggs), out)])
 
-    print(f"=== Q1 on a {N_DEVICES}-way mesh: join + group-by ===")
+    print(f"=== Q1 on a {n_dev}-way mesh: join + group-by ===")
     _, rep1 = restore.run_plan(query(
         {"total": ("sum", "estimated_revenue")}, "q1_out"))
     for j in rep1.jobs:
@@ -71,7 +74,10 @@ def main():
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    import jax
+    if "--child" in sys.argv or jax.default_backend() != "cpu":
         main()
     else:
         env = dict(os.environ)

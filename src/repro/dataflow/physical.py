@@ -24,9 +24,10 @@ from .table import Table, cols_equal, hash_columns
 _U32_MAX = jnp.uint32(0xFFFFFFFF)
 
 # Pallas kernel integration for the relational hot spots (join probe,
-# segment aggregation).  interpret=True executes the kernel bodies in
-# Python — correct everywhere, fast only on real TPUs — so the switch is
-# explicit rather than automatic.
+# segment aggregation, the exchange's partition scatter).  On a TPU
+# backend the kernels always run, compiled; elsewhere they are opt-in,
+# because the Pallas interpreter is correct but slow
+# (``repro.kernels.interpret`` decides compiled vs interpreted).
 _USE_PALLAS = False
 
 
@@ -74,12 +75,6 @@ def _key_hashes(t: Table, keys, seed: int,
     if hc is None:
         return hash_columns(t, keys, seed=seed)
     return hc.hashes(t, keys, seed)
-
-
-def _pad1(a: jnp.ndarray, pad: int, value) -> jnp.ndarray:
-    if pad == 0:
-        return a
-    return jnp.concatenate([a, jnp.full((pad,), value, a.dtype)])
 
 
 def _sort_by_keys(t: Table, keys,
@@ -133,15 +128,8 @@ def _segment_aggregate(t: Table, keys, aggs, order, new_seg) -> Table:
     def _segsum(v):
         if use_pallas():
             from ..kernels.segment_reduce.ops import segment_sum
-            # pad rows to the tile multiple instead of bailing to the
-            # dense fallback: padded rows carry value 0 and the
-            # out-of-range segment id `cap`, so the kernel drops them
-            pad = (-cap) % min(256, cap)
-            return segment_sum(_pad1(v, pad, 0)[:, None],
-                               _pad1(seg_id, pad, cap),
-                               num_segments=cap, impl="pallas",
-                               interpret=jax.default_backend() != "tpu"
-                               )[:, 0]
+            return segment_sum(v[:, None], seg_id, num_segments=cap,
+                               impl="pallas")[:, 0]
         return jax.ops.segment_sum(v, seg_id, num_segments=cap)
 
     ones = sv.astype(jnp.float32)
@@ -352,15 +340,12 @@ def op_join(left: Table, right: Table, lkeys, rkeys,
 
     h_l = (pre_left[0] if pre_left is not None
            else _key_hashes(left, lkeys, 0, hc))
-    if use_pallas():
+    from ..kernels.hash_join import hash_join
+    if use_pallas() and cap_r <= hash_join.MAX_BUILD_ROWS:
+        # the kernel holds the build side whole in SMEM and counts ranks
+        # in O(left x right) compares: dimension-table builds only
         from ..kernels.hash_join.ops import probe
-        # pad probe lanes to the tile multiple (extra lanes are sliced
-        # off) so the kernel path covers every capacity
-        n = h_l.shape[0]
-        pad = (-n) % min(256, n)
-        pos = probe(_pad1(h_l, pad, 0), h_r_sorted, impl="pallas",
-                    tile_n=256,
-                    interpret=jax.default_backend() != "tpu")[:n]
+        pos = probe(h_l, h_r_sorted, impl="pallas")
     else:
         pos = jnp.searchsorted(h_r_sorted, h_l, side="left")
     cand = jnp.clip(pos[:, None] + jnp.arange(probe_w)[None, :], 0, cap_r - 1)

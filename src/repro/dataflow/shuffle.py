@@ -87,12 +87,11 @@ def _exchange(local: Table, keys, n_shards: int, bucket: int, axis: str):
     behind it."""
     h1 = key_hash(local, keys, seed=0)
     tile = autotune.choose("partition_scatter", local.capacity, "uint32",
-                           "tile_n", 256)
+                           "tile_n", 1024)
     from ..kernels.radix_partition.ops import scatter_slots
     slot, overflow = scatter_slots(
         partition_finalize(h1), local.valid, n_parts=n_shards, bucket=bucket,
-        impl="pallas" if use_pallas() else "ref", tile_n=tile,
-        interpret=jax.default_backend() != "tpu")
+        impl="pallas" if use_pallas() else "ref", tile_n=tile)
     overflow = jax.lax.psum(overflow, axis)
 
     cols = dict(local.columns)

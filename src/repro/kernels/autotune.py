@@ -2,11 +2,14 @@
 
 Two halves:
 
-* **TuningTable** — a persisted JSON map from ``op|rows-bucket|dtype``
-  keys to ``{param: value}`` choices.  Rows are bucketed to the next
-  power of two so one tuning run covers a whole size class.  Loading a
-  missing or corrupt table yields an empty one (graceful fallback to
-  defaults), and save -> load round-trips bit-exactly.
+* **TuningTable** — a persisted JSON map from
+  ``device-kind|op|rows-bucket|dtype`` keys to ``{param: value}``
+  choices.  Rows are bucketed to the next power of two so one tuning
+  run covers a whole size class; the device kind
+  (``jax.Device.device_kind``) keeps a table tuned on one backend from
+  steering another's tiles.  Loading a missing or corrupt table yields
+  an empty one (graceful fallback to defaults), and save -> load
+  round-trips bit-exactly.
 
 * **tune()** — pick a value for one parameter: a caller-supplied
   roofline price function (``roofline.analysis.predict_tile_time_s``
@@ -19,7 +22,9 @@ Two halves:
 Runtime consumers call :func:`choose`, which returns the caller's
 default unless tuning is enabled (``RESTORE_AUTOTUNE=1``) AND the table
 has an entry — so the tuner is inert by default and dropping the table
-file merely reverts every knob to its built-in default.  Tuned knobs:
+file merely reverts every knob to its built-in default.  The table lives
+at ``<checkout>/.restore_tuning.json`` (git-ignored) unless
+``RESTORE_AUTOTUNE_TABLE`` names another file.  Tuned knobs:
 
 * ``("partition_scatter", rows, "uint32") / "tile_n"`` — Pallas grid
   tile of the fused partition+scatter kernel (dataflow/shuffle.py).
@@ -32,12 +37,19 @@ from __future__ import annotations
 import json
 import os
 import statistics
+from pathlib import Path
 from typing import Callable, Dict, Optional, Sequence
 
 DEFAULT_TABLE_ENV = "RESTORE_AUTOTUNE_TABLE"
 ENABLE_ENV = "RESTORE_AUTOTUNE"
-DEFAULT_TABLE_PATH = os.path.join(
-    os.path.expanduser("~"), ".cache", "restore_tuning.json")
+DEFAULT_TABLE_PATH = str(Path(__file__).resolve().parents[3]
+                         / ".restore_tuning.json")
+
+
+def device_kind() -> str:
+    """Kind of the device JAX computes on, e.g. ``"TPU v5 lite"``."""
+    import jax
+    return jax.devices()[0].device_kind
 
 
 def rows_bucket(rows: int) -> int:
@@ -53,19 +65,22 @@ class TuningTable:
         self.entries: Dict[str, Dict] = dict(entries or {})
 
     @staticmethod
-    def key(op: str, rows: int, dtype: str) -> str:
-        return f"{op}|{rows_bucket(rows)}|{dtype}"
+    def key(op: str, rows: int, dtype: str,
+            kind: Optional[str] = None) -> str:
+        """``kind`` defaults to the current device's kind."""
+        return f"{kind or device_kind()}|{op}|{rows_bucket(rows)}|{dtype}"
 
     def get(self, op: str, rows: int, dtype: str, param: str,
-            default=None):
-        ent = self.entries.get(self.key(op, rows, dtype))
+            default=None, kind: Optional[str] = None):
+        ent = self.entries.get(self.key(op, rows, dtype, kind))
         if ent is None:
             return default
         return ent.get(param, default)
 
     def put(self, op: str, rows: int, dtype: str, param: str,
-            value) -> None:
-        self.entries.setdefault(self.key(op, rows, dtype), {})[param] = value
+            value, kind: Optional[str] = None) -> None:
+        self.entries.setdefault(self.key(op, rows, dtype, kind),
+                                {})[param] = value
 
     def save(self, path: str) -> None:
         tmp = path + ".tmp"
@@ -147,19 +162,23 @@ def tune(op: str, rows: int, dtype: str, param: str,
 
 
 def scatter_tile_price(rows: int, n_parts: int,
-                       dispatch_cost_s: float = 2e-6):
-    """Roofline price function for the fused partition+scatter tile:
-    bytes touched are fixed (hash + valid in, slot out), so the tile
-    choice trades per-tile dispatch overhead against the VMEM-resident
-    cumsum working set ``tile_n * n_parts`` — priced as extra HBM-class
-    traffic once the working set spills past the tile's own rows."""
+                       dispatch_cost_s: float = 2e-6,
+                       kind: Optional[str] = None):
+    """Roofline price function for the fused partition+scatter tile on
+    a device of ``kind`` (default: the current device; a kind without
+    published peaks raises): bytes touched are fixed (hash + valid in,
+    slot out), so the tile choice trades per-tile dispatch overhead
+    against the VMEM-resident rank working set ``tile_n * n_parts`` —
+    priced as extra HBM-class traffic once the working set spills past
+    the tile's own rows."""
     from ..roofline.analysis import predict_tile_time_s
+    kind = kind or device_kind()
 
     def price(tile_n: int) -> float:
         n_tiles = max(1, rows // max(1, tile_n))
         data = rows * (4 + 1 + 4)
         working = n_tiles * tile_n * n_parts * 4
         return predict_tile_time_s(
-            bytes_accessed=data + working,
+            kind, bytes_accessed=data + working,
             dispatch_overhead_s=n_tiles * dispatch_cost_s)
     return price

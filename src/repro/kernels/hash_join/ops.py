@@ -1,24 +1,14 @@
-"""Jit'd wrapper with impl dispatch + internal padding.
-
-``probe`` accepts ANY probe-side row count: the kernel wants a
-tile-multiple, so the probe lane is zero-padded and the positions
-sliced back (padded lookups are discarded).
-"""
-import jax.numpy as jnp
-
+"""Jit'd wrapper with impl dispatch.  ``probe`` accepts ANY probe-side
+row count: the kernel pads the probe lane to whole tiles and slices the
+positions back (padded lookups are discarded)."""
+from ... import kernels
 from .hash_join import join_probe
 from .ref import join_probe_ref
 
 
 def probe(left_hashes, right_hashes_sorted, *, impl: str = "ref",
-          tile_n: int = 256, interpret: bool = True):
+          tile_n: int = 1024, interpret=None):
     if impl == "pallas":
-        n = left_hashes.shape[0]
-        pad = (-n) % min(tile_n, n) if n else 0
-        if pad:
-            left_hashes = jnp.concatenate(
-                [left_hashes, jnp.zeros((pad,), left_hashes.dtype)])
-        pos = join_probe(left_hashes, right_hashes_sorted,
-                         tile_n=tile_n, interpret=interpret)
-        return pos[:n]
+        return join_probe(left_hashes, right_hashes_sorted, tile_n=tile_n,
+                          interpret=kernels.resolve_interpret(interpret))
     return join_probe_ref(left_hashes, right_hashes_sorted)

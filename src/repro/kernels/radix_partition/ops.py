@@ -1,12 +1,14 @@
-"""Jit'd wrappers with impl dispatch + internal padding.
+"""Jit'd wrappers with impl dispatch.
 
-Both entry points accept ANY row count: inputs are padded with invalid
-rows up to the tile multiple before the kernel and sliced back after,
-so callers never have to reason about tile granularity (padded rows are
-invalid, which both kernels park/drop by construction).
+Both entry points accept ANY row count: the kernels pad with invalid
+rows to whole tiles and slice back, so callers never reason about tile
+granularity (padded rows are invalid, which both kernels park/drop by
+construction).  The ref tiles its histogram the same way the ops
+wrapper always did, padding to a multiple of ``min(tile_n, N)``.
 """
 import jax.numpy as jnp
 
+from ... import kernels
 from .radix_partition import partition_scatter, radix_partition
 from .ref import partition_scatter_ref, radix_partition_ref
 
@@ -21,29 +23,26 @@ def _pad_invalid(hashes, valid, tile_n):
 
 
 def partition(hashes, valid, *, n_parts: int, impl: str = "ref",
-              tile_n: int = 256, interpret: bool = True):
+              tile_n: int = 256, interpret=None):
     h, v, n = _pad_invalid(hashes, valid, tile_n)
     if impl == "pallas":
-        pid, hist = radix_partition(h, v, n_parts=n_parts,
-                                    tile_n=tile_n, interpret=interpret)
+        pid, hist = radix_partition(
+            h, v, n_parts=n_parts, tile_n=tile_n,
+            interpret=kernels.resolve_interpret(interpret))
     else:
-        # the ref reshapes rows into tiles for the per-tile hist, so it
-        # needs the same invalid-padding the kernel gets
         pid, hist = radix_partition_ref(h, v, n_parts=n_parts,
                                         tile_n=tile_n)
     return pid[:n], hist
 
 
 def scatter_slots(hashes, valid, *, n_parts: int, bucket: int,
-                  impl: str = "ref", tile_n: int = 256,
-                  interpret: bool = True):
+                  impl: str = "ref", tile_n: int = 1024, interpret=None):
     """Fused partition + bucket-scatter slots (DESIGN.md §14).  Returns
     (slot (N,) int32 — ``n_parts * bucket`` is the drop slot — and the
     scalar count of valid rows that overflowed their bucket)."""
     if impl == "pallas" and n_parts & (n_parts - 1) == 0:
-        h, v, n = _pad_invalid(hashes, valid, tile_n)
-        slot, ovf = partition_scatter(h, v, n_parts=n_parts, bucket=bucket,
-                                      tile_n=tile_n, interpret=interpret)
-        return slot[:n], ovf
+        return partition_scatter(
+            hashes, valid, n_parts=n_parts, bucket=bucket, tile_n=tile_n,
+            interpret=kernels.resolve_interpret(interpret))
     return partition_scatter_ref(hashes, valid, n_parts=n_parts,
                                  bucket=bucket, tile_n=tile_n)

@@ -1,29 +1,15 @@
-"""Jit'd wrapper with impl dispatch + internal padding.
-
-``segment_sum`` accepts ANY row count: the kernel wants a tile-multiple,
-so rows are padded with out-of-range segment ids, which the kernel drops
-exactly as the ref masks them.
-"""
-import jax.numpy as jnp
-
+"""Jit'd wrapper with impl dispatch.  ``segment_sum`` accepts ANY row
+count: the kernel pads to whole tiles with out-of-range segment ids,
+which it drops exactly as the ref masks them."""
+from ... import kernels
 from .ref import segment_sum_ref
 from .segment_reduce import segment_sum_sorted
 
 
 def segment_sum(values, seg_ids, *, num_segments: int, impl: str = "ref",
-                tile_n: int = 256, interpret: bool = True):
+                tile_n: int = 1024, interpret=None):
     if impl == "pallas":
-        n = values.shape[0]
-        pad = (-n) % min(tile_n, n) if n else 0
-        if pad:
-            values = jnp.concatenate(
-                [values, jnp.zeros((pad,) + values.shape[1:],
-                                   values.dtype)])
-            # padded ids sit past num_segments, keeping the lane sorted
-            # and the rows outside every real segment
-            seg_ids = jnp.concatenate(
-                [seg_ids, jnp.full((pad,), num_segments, seg_ids.dtype)])
-        return segment_sum_sorted(values, seg_ids,
-                                  num_segments=num_segments,
-                                  tile_n=tile_n, interpret=interpret)
+        return segment_sum_sorted(
+            values, seg_ids, num_segments=num_segments, tile_n=tile_n,
+            interpret=kernels.resolve_interpret(interpret))
     return segment_sum_ref(values, seg_ids, num_segments=num_segments)

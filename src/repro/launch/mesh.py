@@ -1,4 +1,4 @@
-"""Production mesh construction + shard_map version compatibility.
+"""Production mesh construction + the repo's one ``shard_map`` entry.
 
 Mesh builders are FUNCTIONS (not module-level constants) so importing
 this module never touches JAX device state — the dry-run sets
@@ -8,22 +8,22 @@ import, and tests/benches must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable ``shard_map``.
+    """``jax.shard_map`` with the varying-manual-axes check off by
+    default.  Every shard_map in this repo goes through here so an API
+    rename surfaces in exactly one place."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
-    Newer JAX exposes ``jax.shard_map`` with the replication check named
-    ``check_vma``; on older releases (our pinned CI floor) the function
-    lives in ``jax.experimental.shard_map`` and the same knob is
-    ``check_rep``.  Every shard_map in this repo goes through here so an
-    API rename surfaces in exactly one place."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with auto (compiler-chosen) sharding on every
+    axis: the engine and the models leave intermediate shardings to the
+    compiler, which an explicit-axes mesh (the default) refuses."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_data_mesh(n_shards: int, axis: str = "data"):
@@ -31,14 +31,14 @@ def make_data_mesh(n_shards: int, axis: str = "data"):
     scale-out axis of the relational engine (DESIGN.md §11)."""
     n = len(jax.devices())
     assert n_shards <= n, (n_shards, n)
-    return jax.make_mesh((n_shards,), (axis,))
+    return _mesh((n_shards,), (axis,))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod adds a leading pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -46,7 +46,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     used by distributed tests and the CPU examples."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

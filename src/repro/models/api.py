@@ -9,6 +9,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
 
 from .config import ModelConfig
 from . import encdec as ED
@@ -21,6 +22,16 @@ SHAPES = {
     "decode_32k": (32_768, 128, "decode"),
     "long_500k": (524_288, 1, "decode"),
 }
+
+
+def _explicit_mesh(tree):
+    """The explicit-axes mesh the leaves of ``tree`` are typed on, if
+    any (None for single-device or auto-axes inputs)."""
+    for leaf in jax.tree.leaves(tree):
+        mesh = jax.typeof(leaf).sharding.mesh
+        if not mesh.empty and AxisType.Explicit in mesh.axis_types:
+            return mesh
+    return None
 
 
 def shape_applicable(cfg: ModelConfig, shape: str) -> Tuple[bool, str]:
@@ -45,6 +56,18 @@ class Model:
 
     # ---------------------------------------------------------------- fwd/loss
     def loss_fn(self, params, batch):
+        """Returns ``(loss + 0.01 * aux, (loss, aux))``.  The model is
+        written for compiler-chosen (auto-axes) sharding; inputs typed
+        on an explicit-axes mesh (``jax.make_mesh``'s default) run under
+        auto axes of that mesh, with replicated scalar outputs."""
+        mesh = _explicit_mesh((params, batch))
+        if mesh is None:
+            return self._loss(params, batch)
+        with jax.sharding.use_abstract_mesh(mesh):
+            return jax.sharding.auto_axes(
+                self._loss, out_sharding=(P(), (P(), P())))(params, batch)
+
+    def _loss(self, params, batch):
         cfg = self.cfg
         if cfg.family == "encdec":
             logits, aux = ED.encdec_forward(

@@ -9,8 +9,9 @@ depth extrapolation (launch/dryrun.py); collective bytes are parsed from
 the post-SPMD HLO text (shapes there are already per-shard, so dividing
 by the chip count again would double-count).
 
-Hardware constants: TPU v5e-class — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants come from :data:`PEAKS`, one table keyed by
+``jax.Device.device_kind``; a kind that is not in it is an error, never
+a default.  The dry-run models v5e pods (:data:`DRYRUN_KIND`).
 """
 from __future__ import annotations
 
@@ -20,24 +21,40 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+# Published per-chip peaks by device kind.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of
+# HBM at 819 GB/s, 1,600 Gbit/s of interconnect over 4 links.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes": 16e9, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9},
+}
+DRYRUN_KIND = "TPU v5 lite"
 
 N_CHIPS = {"16x16": 256, "2x16x16": 512}
 
 
-def predict_tile_time_s(bytes_accessed: float, flops: float = 0.0,
+def peaks(kind: str) -> dict:
+    """Published peaks of one chip of ``kind``; KeyError if unknown."""
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
+
+
+def predict_tile_time_s(kind: str, bytes_accessed: float,
+                        flops: float = 0.0,
                         collective_bytes: float = 0.0,
                         dispatch_overhead_s: float = 0.0) -> float:
-    """Price one candidate kernel/exchange configuration by the same
-    three-term roofline that scores whole dry-run cells: the dominant of
-    compute, HBM, and ICI time, plus a caller-modeled fixed dispatch
-    cost (per-tile grid overhead, collective launch).  Consumed by
-    ``kernels/autotune.py`` to prune a candidate grid down to the few
-    configurations worth actually measuring."""
-    return max(flops / PEAK_FLOPS, bytes_accessed / HBM_BW,
-               collective_bytes / ICI_BW) + dispatch_overhead_s
+    """Price one candidate kernel/exchange configuration on a chip of
+    ``kind`` by the same three-term roofline that scores whole dry-run
+    cells: the dominant of compute, HBM, and ICI time, plus a
+    caller-modeled fixed dispatch cost (per-tile grid overhead,
+    collective launch).  Consumed by ``kernels/autotune.py`` to prune a
+    candidate grid down to the few configurations worth measuring."""
+    p = peaks(kind)
+    return max(flops / p["flops"], bytes_accessed / p["hbm_bw"],
+               collective_bytes / p["ici_link_bw"]) + dispatch_overhead_s
 
 
 def model_flops(report: dict) -> float:
@@ -66,9 +83,10 @@ def analyze_cell(report: dict) -> Optional[dict]:
     # depth-extrapolation noise can drive tiny cells negative — clamp
     coll_dev = max(sum(ce["collective_bytes"].values()), 0.0)
 
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    p = peaks(DRYRUN_KIND)
+    t_compute = flops_dev / p["flops"]
+    t_memory = bytes_dev / p["hbm_bw"]
+    t_coll = coll_dev / p["ici_link_bw"]
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
@@ -77,7 +95,7 @@ def analyze_cell(report: dict) -> Optional[dict]:
     hlo_global = flops_dev * chips
     useful = mf / hlo_global if hlo_global else float("nan")
     # roofline fraction: useful work vs what the dominant term costs
-    t_ideal = (mf / chips) / PEAK_FLOPS
+    t_ideal = (mf / chips) / p["flops"]
     frac = t_ideal / max(terms[dominant], 1e-30)
 
     return {

@@ -30,15 +30,15 @@ def gen_page_views(n_rows: int, seed: int = 0,
                    capacity: int | None = None,
                    n_users: int = N_USERS) -> Table:
     rng = np.random.default_rng(seed)
-    users = [f"user{i:04d}" for i in range(n_users)]
-    terms = [f"term{i:03d}" for i in range(50)]
+    # encode each distinct string once and index the codes: the same
+    # bytes as encoding row by row, at numpy speed for chip-sized tables
+    users = encode_strings([f"user{i:04d}" for i in range(n_users)])
+    terms = encode_strings([f"term{i:03d}" for i in range(50)])
     return Table.from_numpy({
-        "user": encode_strings([users[i] for i in
-                                rng.integers(0, n_users, n_rows)]),
+        "user": users[rng.integers(0, n_users, n_rows)],
         "action": rng.integers(1, 3, n_rows).astype(np.int32),
         "timespent": rng.integers(0, 100, n_rows).astype(np.int32),
-        "query_term": encode_strings([terms[i] for i in
-                                      rng.integers(0, 50, n_rows)]),
+        "query_term": terms[rng.integers(0, 50, n_rows)],
         "timestamp": rng.integers(0, 24, n_rows).astype(np.int32),
         "estimated_revenue": rng.uniform(0, 100, n_rows)
         .astype(np.float32),

@@ -121,7 +121,22 @@ def test_scatter_tile_price_monotone_dispatch_tradeoff():
     """The roofline price must penalise tiny tiles (dispatch-bound) and
     keep the working-set term finite — a sanity pin for the consumer in
     roofline/analysis.py, not a performance claim."""
-    price = autotune.scatter_tile_price(1 << 16, 8)
+    price = autotune.scatter_tile_price(1 << 16, 8, kind="TPU v5 lite")
     costs = {t: price(t) for t in (64, 256, 1024, 4096)}
     assert all(c > 0 for c in costs.values())
     assert costs[64] > costs[4096], "dispatch overhead dominates tiny tiles"
+
+
+def test_table_keys_by_device_kind():
+    """A table tuned on one backend never steers another's knobs."""
+    t = TuningTable()
+    t.put("partition_scatter", 4096, "uint32", "tile_n", 2048, kind="cpu")
+    assert t.get("partition_scatter", 4096, "uint32", "tile_n",
+                 kind="cpu") == 2048
+    assert t.get("partition_scatter", 4096, "uint32", "tile_n",
+                 default=1024, kind="TPU v5 lite") == 1024
+
+
+def test_price_of_unknown_device_kind_raises():
+    with pytest.raises(KeyError):
+        autotune.scatter_tile_price(1 << 16, 8, kind="cpu")(1024)

@@ -230,3 +230,32 @@ assert any(j.stats.shuffles_skipped > 0 for j in rep.jobs if j.stats), \\
     "re-partitioned-on-read artifact must still skip the exchange"
 print("OK")
 """)
+
+
+def test_union_query_on_data_mesh_matches_single_device():
+    """PigMix L11 (two DISTINCTs and a UNION) on the repo's data mesh.
+    ``jax.make_mesh`` defaults to explicit axes, under which the UNION's
+    concatenation of two row-sharded inputs is refused; the mesh
+    builders ask for auto axes."""
+    run_sub("""
+from jax.sharding import AxisType
+from repro.core.restore import ReStore
+from repro.launch.mesh import make_data_mesh
+from repro.store.artifacts import ArtifactStore, Catalog
+from repro.workloads import pigmix
+
+mesh = make_data_mesh(4)
+assert mesh.axis_types == (AxisType.Auto,), mesh.axis_types
+
+def run(mesh):
+    store = ArtifactStore()
+    cat = Catalog(store)
+    pigmix.register_all(cat, n_rows=1000)
+    res, _ = ReStore(cat, store, mesh=mesh).run(pigmix.L11())
+    return sorted(decode_strings(res["L11_power_users_out"]
+                                 .to_numpy()["user"]))
+
+one, four = run(None), run(mesh)
+assert len(one) > 150 and one == four, (len(one), len(four))
+print("OK")
+""", devices=4)
