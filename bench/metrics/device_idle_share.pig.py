@@ -1,0 +1,10 @@
+"""Per cent of the traced window in which no operation ran on the
+device: 1 - (union of device op intervals) / window, averaged over the
+chips.  Layer: device.  Moves: queries_per_s."""
+
+
+def read(run):
+    t = run.trace
+    if not t or t["busy_s"] is None or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
