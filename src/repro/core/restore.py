@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional
 
+from .. import obs
 from ..dataflow.builder import as_plan
 from ..dataflow.compiler import Job, Workflow, compile_workflow
 from ..dataflow.executor import Engine, JobStats
@@ -133,7 +134,9 @@ class ReStore:
         ``PhysicalPlan`` or a Pig-style ``dataflow.builder.Dataflow``
         (lowered via its ``build()``), compile to a workflow and run it.
         Returns ``(results, RunReport)``."""
-        return self.run_workflow(compile_workflow(as_plan(query)))
+        with obs.span("restore.driver.compile"):
+            wf = compile_workflow(as_plan(query))
+        return self.run_workflow(wf)
 
     def run_plan(self, plan: PhysicalPlan):
         """Deprecated alias for :meth:`run` (pre-§16 signature; kept so
@@ -167,33 +170,41 @@ class ReStore:
                 try:
                     for job in wf.jobs:
                         reports.append(self._process_job(job))
-                    results = {user: self.store.get(ds)
-                               for user, ds in wf.final_outputs.items()}
+                    with obs.span("restore.driver.gather"):
+                        results = {user: self.store.get(ds) for user, ds
+                                   in wf.final_outputs.items()}
                     break
                 except ArtifactError as e:
                     if e.name is None or cycle == 2:
                         raise
                     self._degrade(e)
-        finally:
-            # unpin mirrors the two pin sites exactly (boundary at run
-            # start, _pin_for_run increments during the run): pins are
-            # refcounted so concurrent workflows sharing the repository
-            # don't release each other's protection
-            self.repo.unpin(boundary)
-            self.repo.unpin(self._run_pins)
-            self._run_pins = set()
-        self.repo.rebalance()
-        # workflow end is a durability point for the write-behind store.
-        # A permanent flush failure does not invalidate the results (they
-        # were computed on device); the failed artifacts are already
-        # de-advertised — report them instead of failing the run.
-        flush_failures: List[str] = []
-        try:
-            self.store.flush()
-        except ArtifactFlushError as e:
-            flush_failures = sorted(e.failures)
+        except BaseException:
+            self._unpin_run(boundary)
+            raise
+        with obs.span("restore.driver.settle"):
+            self._unpin_run(boundary)
+            self.repo.rebalance()
+            # workflow end is a durability point for the write-behind
+            # store.  A permanent flush failure does not invalidate the
+            # results (they were computed on device); the failed
+            # artifacts are already de-advertised — report them instead
+            # of failing the run.
+            flush_failures: List[str] = []
+            try:
+                self.store.flush()
+            except ArtifactFlushError as e:
+                flush_failures = sorted(e.failures)
         return results, RunReport(reports, degraded=self._degraded,
                                   flush_failures=flush_failures)
+
+    def _unpin_run(self, boundary) -> None:
+        """Release a run's pins: they mirror the two pin sites exactly
+        (boundary at run start, _pin_for_run increments during the run).
+        Pins are refcounted so concurrent workflows sharing the
+        repository don't release each other's protection."""
+        self.repo.unpin(boundary)
+        self.repo.unpin(self._run_pins)
+        self._run_pins = set()
 
     def maintain(self, mode: str = "auto", only=None) -> Dict[str, int]:
         """Incremental maintenance entry point (DESIGN.md §12): refresh
@@ -242,24 +253,10 @@ class ReStore:
         if self.repo.pending_refresh:
             self.repo.refresh_pending(job.plan, self.engine, self.catalog,
                                       self.store)
-        # a job whose outputs all exist is fully answered by the store
-        if all(self.store.exists(o) for o in job.outputs):
-            # this is the hottest reuse path (identical recurring jobs):
-            # credit the backing entries — resolving aliases, since a
-            # previously reused job serves its output THROUGH an alias
-            # to the backing artifact — or budget eviction would rank
-            # exactly the most-reused artifacts as unused
-            outs = {self.store._resolve(o) for o in job.outputs} \
-                | set(job.outputs)
-            cm = self.repo.cost_model
-            for e in self.repo.entries:
-                if e.artifact in outs:
-                    saved = cm.savings_per_reuse_s(
-                        e.producer_cost_s or e.exec_time_s, e.bytes_out)
-                    self.repo.record_use(e, saved_s=max(saved, 0.0))
-            self._pin_for_run(outs)
-            return JobReport(job.job_id, False, list(job.outputs), [], None,
-                             job.plan.n_ops(), 0)
+        with obs.span("restore.driver.reuse"):
+            reused = self._reuse_whole_job(job)
+        if reused is not None:
+            return reused
 
         n_before = job.plan.n_ops()
         n_semantic = 0
@@ -364,6 +361,28 @@ class ReStore:
                          stored, stats, n_before, exec_plan.n_ops(),
                          rejected_candidates=rejected,
                          n_semantic=n_semantic)
+
+    def _reuse_whole_job(self, job: Job) -> Optional[JobReport]:
+        """The report of a job whose outputs all exist, which the store
+        answers whole; None where one is missing."""
+        if not all(self.store.exists(o) for o in job.outputs):
+            return None
+        # this is the hottest reuse path (identical recurring jobs):
+        # credit the backing entries — resolving aliases, since a
+        # previously reused job serves its output THROUGH an alias to
+        # the backing artifact — or budget eviction would rank exactly
+        # the most-reused artifacts as unused
+        outs = {self.store._resolve(o) for o in job.outputs} \
+            | set(job.outputs)
+        cm = self.repo.cost_model
+        for e in self.repo.entries:
+            if e.artifact in outs:
+                saved = cm.savings_per_reuse_s(
+                    e.producer_cost_s or e.exec_time_s, e.bytes_out)
+                self.repo.record_use(e, saved_s=max(saved, 0.0))
+        self._pin_for_run(outs)
+        return JobReport(job.job_id, False, list(job.outputs), [], None,
+                         job.plan.n_ops(), 0)
 
     def _pin_for_run(self, names) -> None:
         """Pin artifacts until the current workflow run finishes (used

@@ -22,6 +22,7 @@ from typing import Callable, Dict, List, Tuple
 
 import jax
 
+from .. import obs
 from ..core.plan import (Partitioning, load_partition_demands,
                          plan_physical_props)
 from ..kernels import autotune
@@ -338,6 +339,41 @@ class Engine:
             return {n: overrides[n] if n in overrides else self._dataset(n)
                     for n in input_names}
 
+        def execute(f):
+            """One load -> execute -> store cycle of program ``f``."""
+            with obs.span("restore.engine.load"):
+                inputs = load_inputs()                           # T_load
+            with obs.span("restore.engine.dispatch"):
+                outputs, stats = f(inputs)
+            # one synchronization point per job (not per output): wait for
+            # the whole output pytree at once
+            with obs.span("restore.engine.wait"):
+                outputs = jax.block_until_ready(outputs)
+            if not transient:
+                for name, t in outputs.items():                  # T_store
+                    self.store.put(name, t,
+                                   partitioning=out_parts.get(name))
+            return inputs, outputs, stats
+
+        def scalars(inputs, outputs, stats, uid_by_fp, fps):
+            """The job's row and overflow counts, read to the host."""
+            with obs.span("restore.engine.stats"):
+                rows_in = sum(int(t.num_valid()) for t in inputs.values())
+                rows_out = sum(int(t.num_valid()) for t in outputs.values())
+                # stats arrive keyed by the cached plan's op uids;
+                # translate to the current plan's uids through the shared
+                # fingerprints
+                op_rows = {}
+                for op in job.plan.topo():
+                    s = stats.get(uid_by_fp.get(fps[id(op)]))
+                    if s is not None:
+                        op_rows[op.uid] = int(s["rows_out"])
+                ovf = sum(int(s.get("join_overflow", 0))
+                          for s in stats.values())
+                sh_ovf = sum(int(s.get("shuffle_overflow", 0))
+                             for s in stats.values())
+            return rows_in, rows_out, op_rows, ovf, sh_ovf
+
         if self.measure_exec:   # warm jit + OS page cache off the clock
             warm, _ = fn(load_inputs())
             jax.block_until_ready(warm)
@@ -347,15 +383,7 @@ class Engine:
         reps = self.repeats if self.measure_exec else 1
         for _ in range(reps):
             t0 = time.perf_counter()
-            inputs = load_inputs()                               # T_load
-            outputs, stats = fn(inputs)
-            # one synchronization point per job (not per output): wait for
-            # the whole output pytree at once
-            outputs = jax.block_until_ready(outputs)
-            if not transient:
-                for name, t in outputs.items():                  # T_store
-                    self.store.put(name, t,
-                                   partitioning=out_parts.get(name))
+            inputs, outputs, stats = execute(fn)
             walls.append(time.perf_counter() - t0)
             if self.measure_exec:
                 # drain the write-behind queue between reps so background
@@ -364,20 +392,10 @@ class Engine:
                 self.store.flush()
         wall = sorted(walls)[len(walls) // 2]
 
-        rows_in = sum(int(t.num_valid()) for t in inputs.values())
+        rows_in, rows_out, op_rows, ovf, sh_ovf = scalars(
+            inputs, outputs, stats, uid_by_fp, fps)
         bytes_in = sum(t.nbytes() for t in inputs.values())
-        rows_out = sum(int(t.num_valid()) for t in outputs.values())
         bytes_out = sum(t.nbytes() for t in outputs.values())
-        # stats arrive keyed by the cached plan's op uids; translate to
-        # the current plan's uids through the shared fingerprints
-        op_rows = {}
-        for op in job.plan.topo():
-            s = stats.get(uid_by_fp.get(fps[id(op)]))
-            if s is not None:
-                op_rows[op.uid] = int(s["rows_out"])
-        ovf = sum(int(s.get("join_overflow", 0)) for s in stats.values())
-        sh_ovf = sum(int(s.get("shuffle_overflow", 0))
-                     for s in stats.values())
         retries = 0
         if sh_ovf > 0 and self.mesh is not None:
             # lossless retry (DESIGN.md §14): the bounded buckets
@@ -395,24 +413,12 @@ class Engine:
                 jax.block_until_ready(warm)
                 del warm
             t0 = time.perf_counter()
-            inputs = load_inputs()
-            outputs, stats = fn2(inputs)
-            outputs = jax.block_until_ready(outputs)
-            if not transient:
-                for name, t in outputs.items():
-                    self.store.put(name, t,
-                                   partitioning=out_parts.get(name))
+            inputs, outputs, stats = execute(fn2)
             wall += time.perf_counter() - t0
             retries = 1
-            rows_out = sum(int(t.num_valid()) for t in outputs.values())
+            rows_in, rows_out, op_rows, ovf, _ = scalars(
+                inputs, outputs, stats, uid_by_fp, fps)
             bytes_out = sum(t.nbytes() for t in outputs.values())
-            op_rows = {}
-            for op in job.plan.topo():
-                s = stats.get(uid_by_fp.get(fps[id(op)]))
-                if s is not None:
-                    op_rows[op.uid] = int(s["rows_out"])
-            ovf = sum(int(s.get("join_overflow", 0))
-                      for s in stats.values())
         op_cost = attribute_op_costs(job.plan, op_rows, wall)
         js = JobStats(job.job_id, wall, rows_in, bytes_in,
                       rows_out, bytes_out, op_rows, ovf, op_cost,

@@ -508,81 +508,85 @@ def execute_plan(plan: PhysicalPlan, datasets: Dict[str, Table],
         return True
 
     for op in plan.topo():
-        p = op.params
-        ins = [values[id(i)] for i in op.inputs]
-        extra: Dict[str, jnp.ndarray] = {}
-        if op.kind == "LOAD":
-            v = datasets[p["dataset"]]
-        elif op.kind == "FILTER":
-            v = op_filter(ins[0], p["pred"])
-        elif op.kind == "PROJECT":
-            v = op_project(ins[0], p["cols"])
-        elif op.kind == "FOREACH":
-            v = op_foreach(ins[0], p["gens"])
-        elif op.kind == "JOIN":
-            if mesh is not None:
-                v, jpre, sh_ovf, ovf = distributed_join(
-                    ins[0], ins[1], p["left_keys"], p["right_keys"], mesh,
-                    axis=shuffle_axis, expansion=p.get("expansion", 1),
-                    skew_factor=skew_factor,
-                    co_left=_skip(op, 0, ins[0]),
-                    co_right=_skip(op, 1, ins[1]),
-                    return_pre=True)
-                if jpre is not None:
-                    # left-side names survive the join rename rule
-                    # unchanged, so the lane keys are the left keys
-                    pres[id(v)] = (tuple(p["left_keys"]), jpre)
-                extra["shuffle_overflow"] = sh_ovf
+        # the operator names the device ops it lowers to (their
+        # metadata), so a device trace can be read per operator
+        with jax.named_scope(op.kind.lower()):
+            p = op.params
+            ins = [values[id(i)] for i in op.inputs]
+            extra: Dict[str, jnp.ndarray] = {}
+            if op.kind == "LOAD":
+                v = datasets[p["dataset"]]
+            elif op.kind == "FILTER":
+                v = op_filter(ins[0], p["pred"])
+            elif op.kind == "PROJECT":
+                v = op_project(ins[0], p["cols"])
+            elif op.kind == "FOREACH":
+                v = op_foreach(ins[0], p["gens"])
+            elif op.kind == "JOIN":
+                if mesh is not None:
+                    v, jpre, sh_ovf, ovf = distributed_join(
+                        ins[0], ins[1], p["left_keys"], p["right_keys"], mesh,
+                        axis=shuffle_axis, expansion=p.get("expansion", 1),
+                        skew_factor=skew_factor,
+                        co_left=_skip(op, 0, ins[0]),
+                        co_right=_skip(op, 1, ins[1]),
+                        return_pre=True)
+                    if jpre is not None:
+                        # left-side names survive the join rename rule
+                        # unchanged, so the lane keys are the left keys
+                        pres[id(v)] = (tuple(p["left_keys"]), jpre)
+                    extra["shuffle_overflow"] = sh_ovf
+                else:
+                    v, ovf = op_join(ins[0], ins[1], p["left_keys"],
+                                     p["right_keys"], p.get("expansion", 1),
+                                     hc)
+                extra["join_overflow"] = ovf
+            elif op.kind == "GROUPBY":
+                if mesh is not None:
+                    entry = pres.get(id(ins[0]))
+                    lane = (entry[1] if entry is not None
+                            and entry[0] == tuple(p["keys"]) else None)
+                    v, ovf = distributed_groupby(
+                        ins[0], p["keys"], p["aggs"], mesh, axis=shuffle_axis,
+                        skew_factor=skew_factor,
+                        co_partitioned=_skip(op, 0, ins[0]),
+                        lossless=lossless, pre_lane=lane)
+                    extra["shuffle_overflow"] = ovf
+                else:
+                    v = op_groupby(ins[0], p["keys"], p["aggs"], hc)
+            elif op.kind == "COGROUP":
+                if mesh is not None:
+                    co = _skip(op, 0, ins[0]) and _skip(op, 1, ins[1])
+                    v, ovf = distributed_cogroup(
+                        ins[0], ins[1], p["keys_left"], p["keys_right"],
+                        p["aggs_left"], p["aggs_right"], mesh,
+                        axis=shuffle_axis, skew_factor=skew_factor,
+                        co_partitioned=co, lossless=lossless)
+                    extra["shuffle_overflow"] = ovf
+                else:
+                    v = op_cogroup(ins[0], ins[1], p["keys_left"],
+                                   p["keys_right"], p["aggs_left"],
+                                   p["aggs_right"], hc)
+            elif op.kind == "DISTINCT":
+                if mesh is not None:
+                    v, ovf = distributed_distinct(
+                        ins[0], mesh, axis=shuffle_axis,
+                        skew_factor=skew_factor,
+                        co_partitioned=_skip(op, 0, ins[0]),
+                        lossless=lossless)
+                    extra["shuffle_overflow"] = ovf
+                else:
+                    v = op_distinct(ins[0], hc)
+            elif op.kind == "UNION":
+                v = op_union(ins[0], ins[1])
+            elif op.kind == "SPLIT":
+                v = ins[0]
+            elif op.kind == "STORE":
+                v = op_store(ins[0])
+                outputs[p["name"]] = v
             else:
-                v, ovf = op_join(ins[0], ins[1], p["left_keys"],
-                                 p["right_keys"], p.get("expansion", 1), hc)
-            extra["join_overflow"] = ovf
-        elif op.kind == "GROUPBY":
-            if mesh is not None:
-                entry = pres.get(id(ins[0]))
-                lane = (entry[1] if entry is not None
-                        and entry[0] == tuple(p["keys"]) else None)
-                v, ovf = distributed_groupby(
-                    ins[0], p["keys"], p["aggs"], mesh, axis=shuffle_axis,
-                    skew_factor=skew_factor,
-                    co_partitioned=_skip(op, 0, ins[0]),
-                    lossless=lossless, pre_lane=lane)
-                extra["shuffle_overflow"] = ovf
-            else:
-                v = op_groupby(ins[0], p["keys"], p["aggs"], hc)
-        elif op.kind == "COGROUP":
-            if mesh is not None:
-                co = _skip(op, 0, ins[0]) and _skip(op, 1, ins[1])
-                v, ovf = distributed_cogroup(
-                    ins[0], ins[1], p["keys_left"], p["keys_right"],
-                    p["aggs_left"], p["aggs_right"], mesh,
-                    axis=shuffle_axis, skew_factor=skew_factor,
-                    co_partitioned=co, lossless=lossless)
-                extra["shuffle_overflow"] = ovf
-            else:
-                v = op_cogroup(ins[0], ins[1], p["keys_left"],
-                               p["keys_right"], p["aggs_left"],
-                               p["aggs_right"], hc)
-        elif op.kind == "DISTINCT":
-            if mesh is not None:
-                v, ovf = distributed_distinct(
-                    ins[0], mesh, axis=shuffle_axis,
-                    skew_factor=skew_factor,
-                    co_partitioned=_skip(op, 0, ins[0]),
-                    lossless=lossless)
-                extra["shuffle_overflow"] = ovf
-            else:
-                v = op_distinct(ins[0], hc)
-        elif op.kind == "UNION":
-            v = op_union(ins[0], ins[1])
-        elif op.kind == "SPLIT":
-            v = ins[0]
-        elif op.kind == "STORE":
-            v = op_store(ins[0])
-            outputs[p["name"]] = v
-        else:
-            raise ValueError(op.kind)
-        values[id(op)] = v
-        extra["rows_out"] = v.num_valid()
-        stats[op.uid] = extra
+                raise ValueError(op.kind)
+            values[id(op)] = v
+            extra["rows_out"] = v.num_valid()
+            stats[op.uid] = extra
     return outputs, stats

@@ -155,22 +155,28 @@ class Table:
         order = jnp.clip(order, 0, self.capacity - 1)
         return self.gather(order, jnp.arange(self.capacity) < cnt[-1])
 
-    def host_compact(self, capacity: int, nvalid: int
-                     ) -> "Dict[str, np.ndarray]":
-        """Numpy-side compaction for the store's write path: extract the
-        ``nvalid`` valid rows (stable), pad to ``capacity``.  Returns
-        column arrays plus ``__valid__``; runs off the device and off the
-        timed path (flusher thread)."""
-        mask = np.asarray(self.valid).astype(bool)
-        out: Dict[str, np.ndarray] = {}
-        for n, c in self.columns.items():
-            a = np.asarray(c)[mask][:capacity]
-            if len(a) < capacity:
-                pad = [(0, capacity - len(a))] + [(0, 0)] * (a.ndim - 1)
-                a = np.pad(a, pad)
-            out[n] = a
-        out["__valid__"] = np.arange(capacity) < nvalid
-        return out
+    def to_host(self) -> "Tuple[Dict[str, np.ndarray], np.ndarray]":
+        """Every column and the validity mask, copied to the host."""
+        return ({n: np.asarray(c) for n, c in self.columns.items()},
+                np.asarray(self.valid).astype(bool))
+
+
+def host_compact(cols: Dict[str, np.ndarray], mask: np.ndarray,
+                 capacity: int, nvalid: int) -> Dict[str, np.ndarray]:
+    """Numpy-side compaction for the store's write path, of columns and
+    mask already on the host (``Table.to_host``): extract the ``nvalid``
+    valid rows (stable), pad to ``capacity``.  Returns column arrays plus
+    ``__valid__``; runs off the device and off the timed path (flusher
+    thread)."""
+    out: Dict[str, np.ndarray] = {}
+    for n, a in cols.items():
+        a = a[mask][:capacity]
+        if len(a) < capacity:
+            pad = [(0, capacity - len(a))] + [(0, 0)] * (a.ndim - 1)
+            a = np.pad(a, pad)
+        out[n] = a
+    out["__valid__"] = np.arange(capacity) < nvalid
+    return out
 
 
 def concat_tables(parts, capacity: int | None = None) -> Table:

@@ -30,10 +30,12 @@ Scheduling and robustness:
 from __future__ import annotations
 
 import collections
+import itertools
 import threading
 import time
 from typing import Dict, List, Optional
 
+from .. import obs
 from ..core.mqo import optimize_batch
 from ..core.plan import PhysicalPlan, plan_signature
 from ..core.repository import Repository
@@ -54,16 +56,23 @@ class ServiceClosed(RuntimeError):
     """submit() after stop()."""
 
 
+_ticket_ids = itertools.count(1)
+
+
 class Ticket:
-    """Handle for one submitted workflow."""
+    """Handle for one submitted workflow.  ``id`` is the request id of
+    the program's spans (``repro.obs``); a singleflight follower shares
+    its leader's."""
 
     def __init__(self, plan: PhysicalPlan, tenant: str, key: str,
-                 deadline_s: Optional[float]):
+                 deadline_s: Optional[float], id: Optional[int] = None):
         self.plan = plan
         self.tenant = tenant
         self.key = key
         self.deadline_s = deadline_s
+        self.id = next(_ticket_ids) if id is None else id
         self.submitted_at = time.time()
+        self.queued = None              # its open queue span, if traced
         self.attempts = 0
         self.followers: List["Ticket"] = []
         self._ev = threading.Event()
@@ -84,6 +93,11 @@ class Ticket:
         if self._error is not None:
             raise self._error
         return self._results, self._report
+
+    def _dequeued(self) -> None:
+        if self.queued is not None:
+            self.queued.end()
+            self.queued = None
 
     def _resolve(self, results, report) -> None:
         self._results, self._report = results, report
@@ -206,7 +220,7 @@ class ReStoreService:
             if self.singleflight:
                 leader = self._inflight.get(key)
                 if leader is not None:
-                    t = Ticket(plan, tenant, key, deadline_s)
+                    t = Ticket(plan, tenant, key, deadline_s, id=leader.id)
                     leader.followers.append(t)
                     self._stats["singleflight_hits"] += 1
                     self._tenant(tenant)["singleflight_hits"] += 1
@@ -317,6 +331,7 @@ class ReStoreService:
             self._rr.append(t.tenant)
         q.append(t)
         self._qsize += 1
+        t.queued = obs.begin("restore.service.queue", request=t.id)
 
     # ----------------------------------------------------------- workers
     def _next_ticket_locked(self) -> Optional[Ticket]:
@@ -335,6 +350,7 @@ class ReStoreService:
                 continue
             t = q.popleft()
             self._qsize -= 1
+            t._dequeued()
             return t
         return None
 
@@ -348,60 +364,73 @@ class ReStoreService:
                     t = self._next_ticket_locked()
                 if t is None:           # closed and drained
                     return
-                now = time.time()
-                if (t.deadline_s is not None
-                        and now - t.submitted_at > t.deadline_s):
-                    self._stats["timeouts"] += 1
-                    self._finish_locked(
-                        t, error=ServiceTimeout(
-                            f"queued {now - t.submitted_at:.3f}s > "
-                            f"deadline {t.deadline_s:.3f}s"))
-                    self._cv.notify_all()
-                    continue
-                if t.key in self._executing_keys:
-                    # the invariant the singleflight gate exists for;
-                    # asserted == 0 by the bench gate
-                    self._stats["dup_executions"] += 1
-                self._executing_keys.add(t.key)
-                self._executing_by_tenant[t.tenant] = \
-                    self._executing_by_tenant.get(t.tenant, 0) + 1
-                self._cv.notify_all()
-            t.attempts += 1
-            try:
-                if self.job_overhead_s > 0:
-                    time.sleep(self.job_overhead_s)
+                with obs.request(t.id):
+                    started = self._start_locked(t)
+            if started:
+                with obs.request(t.id):
+                    self._execute(driver, t)
+
+    def _start_locked(self, t: Ticket) -> bool:
+        """Mark a taken ticket executing, or fail it if it outlived its
+        deadline in the queue (requeue-or-fail)."""
+        now = time.time()
+        if (t.deadline_s is not None
+                and now - t.submitted_at > t.deadline_s):
+            self._stats["timeouts"] += 1
+            self._finish_locked(
+                t, error=ServiceTimeout(
+                    f"queued {now - t.submitted_at:.3f}s > "
+                    f"deadline {t.deadline_s:.3f}s"))
+            self._cv.notify_all()
+            return False
+        if t.key in self._executing_keys:
+            # the invariant the singleflight gate exists for;
+            # asserted == 0 by the bench gate
+            self._stats["dup_executions"] += 1
+        self._executing_keys.add(t.key)
+        self._executing_by_tenant[t.tenant] = \
+            self._executing_by_tenant.get(t.tenant, 0) + 1
+        self._cv.notify_all()
+        return True
+
+    def _execute(self, driver: ReStore, t: Ticket) -> None:
+        t.attempts += 1
+        try:
+            if self.job_overhead_s > 0:
+                time.sleep(self.job_overhead_s)
+            with obs.span("restore.service.execute"):
                 results, report = driver.run_plan(t.plan)
-            except TransientStoreError as e:
-                if t.attempts < self.max_attempts:
-                    with self._cv:
-                        self._stats["retries"] += 1
-                    # the ticket stays "executing" through the backoff so
-                    # stop(drain=True) cannot slip past it mid-retry
-                    time.sleep(min(self.retry_cap_s,
-                                   self.retry_base_s
-                                   * (2 ** (t.attempts - 1))))
-                    with self._cv:
-                        self._after_exec_locked(t)
-                        self._enqueue_locked(t)
-                        self._cv.notify_all()
-                else:
-                    with self._cv:
-                        self._after_exec_locked(t)
-                        self._finish_locked(t, error=e)
-                        self._cv.notify_all()
-            except BaseException as e:
+        except TransientStoreError as e:
+            if t.attempts < self.max_attempts:
+                with self._cv:
+                    self._stats["retries"] += 1
+                # the ticket stays "executing" through the backoff so
+                # stop(drain=True) cannot slip past it mid-retry
+                time.sleep(min(self.retry_cap_s,
+                               self.retry_base_s
+                               * (2 ** (t.attempts - 1))))
                 with self._cv:
                     self._after_exec_locked(t)
-                    self._finish_locked(t, error=e)
+                    self._enqueue_locked(t)
                     self._cv.notify_all()
             else:
                 with self._cv:
                     self._after_exec_locked(t)
-                    self._stats["degraded"] += report.degraded
-                    self._stats["flush_failures"] += \
-                        len(report.flush_failures)
-                    self._finish_locked(t, results=results, report=report)
+                    self._finish_locked(t, error=e)
                     self._cv.notify_all()
+        except BaseException as e:
+            with self._cv:
+                self._after_exec_locked(t)
+                self._finish_locked(t, error=e)
+                self._cv.notify_all()
+        else:
+            with self._cv:
+                self._after_exec_locked(t)
+                self._stats["degraded"] += report.degraded
+                self._stats["flush_failures"] += \
+                    len(report.flush_failures)
+                self._finish_locked(t, results=results, report=report)
+                self._cv.notify_all()
 
     def _after_exec_locked(self, t: Ticket) -> None:
         self._executing_keys.discard(t.key)
@@ -415,19 +444,19 @@ class ReStoreService:
                        error: Optional[BaseException] = None) -> None:
         """Resolve a ticket (and its singleflight followers) and retire
         its key.  Callers hold the service lock."""
-        if self._inflight.get(t.key) is t:
-            del self._inflight[t.key]
-        tickets = [t] + t.followers
-        for tk in tickets:
-            if error is not None:
-                self._stats["failed"] += 1
-                self._tenant(tk.tenant)["failed"] += 1
-                tk._reject(error)
-            else:
-                self._stats["completed"] += 1
-                self._tenant(tk.tenant)["completed"] += 1
-                tk._resolve(results, report)
-        t.followers = []
+        with obs.span("restore.service.resolve"):
+            if self._inflight.get(t.key) is t:
+                del self._inflight[t.key]
+            for tk in [t] + t.followers:
+                if error is not None:
+                    self._stats["failed"] += 1
+                    self._tenant(tk.tenant)["failed"] += 1
+                    tk._reject(error)
+                else:
+                    self._stats["completed"] += 1
+                    self._tenant(tk.tenant)["completed"] += 1
+                    tk._resolve(results, report)
+            t.followers = []
 
     # ------------------------------------------------------- maintenance
     def _maintain_loop(self, interval_s: float) -> None:
@@ -483,6 +512,7 @@ class ReStoreService:
                     while q:
                         t = q.popleft()
                         self._qsize -= 1
+                        t._dequeued()
                         self._finish_locked(
                             t, error=ServiceClosed("service stopping"))
             while self._qsize or self._executing_keys:

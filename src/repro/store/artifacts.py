@@ -39,8 +39,9 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from ..dataflow.table import (Table, concat_tables, partition_ids_device,
-                              slice_valid)
+from .. import obs
+from ..dataflow.table import (Table, concat_tables, host_compact,
+                              partition_ids_device, slice_valid)
 
 # Default byte bound for the device-resident cache tier.
 DEFAULT_CACHE_BYTES = int(os.environ.get("RESTORE_CACHE_BYTES",
@@ -338,7 +339,7 @@ class _WriteBehind:
         self._store = store
         self._max_depth = max_depth
         self._cv = threading.Condition()
-        # name -> (table, meta, pid) — newest data wins
+        # name -> (table, meta, pid, request id) — newest data wins
         self._jobs: Dict[str, Tuple] = {}
         self._order: "collections.deque[str]" = collections.deque()
         self._queued = set()
@@ -351,7 +352,6 @@ class _WriteBehind:
         self.failures: Dict[str, BaseException] = {}
         self._closed = False
         self._thread: Optional[threading.Thread] = None
-        self.flushed_count = 0
 
     # ------------------------------------------------------------- caller
     def _ensure_thread(self):
@@ -382,7 +382,7 @@ class _WriteBehind:
             while (len(self._order) >= self._max_depth
                    and name not in self._queued):
                 self._cv.wait()
-            self._jobs[name] = (table, meta, pid)
+            self._jobs[name] = (table, meta, pid, obs.request_id())
             if name not in self._queued:
                 self._queued.add(name)
                 self._order.append(name)
@@ -457,8 +457,9 @@ class _WriteBehind:
             compacted = None
             for attempt in range(WRITE_ATTEMPTS):
                 try:
-                    compacted = self._store._write_to_disk(
-                        name, job[0], job[1], pid=job[2])
+                    with obs.request(job[3]):
+                        compacted = self._store._write_to_disk(
+                            name, job[0], job[1], pid=job[2])
                     err = None
                     break
                 except OSError as e:     # transient IO: capped backoff
@@ -493,7 +494,6 @@ class _WriteBehind:
                 # a superseded job's failure is irrelevant — the newer
                 # put will be written (or fail) on its own turn
                 self._writing = None
-                self.flushed_count += 1
                 self._cv.notify_all()
 
 
@@ -674,22 +674,30 @@ class ArtifactStore:
         part = meta.get("partitioning")
         if part is not None:
             return self._write_sharded(name, table, meta, pid)
-        packed = table.host_compact(meta["capacity"], meta["rows"])
+        with obs.span("restore.store.flush.fetch"):
+            host, mask = table.to_host()
+        with obs.span("restore.store.flush.compact"):
+            packed = host_compact(host, mask, meta["capacity"],
+                                  meta["rows"])
+        del host, mask  # the uncompacted copy is not held through the write
         valid = packed.pop("__valid__")
         final = self._path(name)
         self._fault("write", name)
         tmp = tempfile.mkdtemp(dir=self.root, prefix=".tmp-")
         try:
-            data = _npz_bytes(dict(__valid__=valid, **packed))
-            # checksums land in the SAME meta dict put() advertised, so
-            # in-memory readers and the disk manifest agree after flush
-            meta["checksums"] = {"data.npz": zlib.crc32(data)}
-            with open(os.path.join(tmp, "data.npz"), "wb") as f:
-                f.write(data)
-            with open(os.path.join(tmp, "manifest.json"), "w") as f:
-                json.dump(meta, f)
-            self._fault("publish", name, path=tmp)
-            self._publish(tmp, final)
+            with obs.span("restore.store.flush.encode"):
+                data = _npz_bytes(dict(__valid__=valid, **packed))
+                # checksums land in the SAME meta dict put() advertised,
+                # so in-memory readers and the disk manifest agree after
+                # flush
+                meta["checksums"] = {"data.npz": zlib.crc32(data)}
+            with obs.span("restore.store.flush.write"):
+                with open(os.path.join(tmp, "data.npz"), "wb") as f:
+                    f.write(data)
+                with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                    json.dump(meta, f)
+                self._fault("publish", name, path=tmp)
+                self._publish(tmp, final)
         except SimulatedCrash:
             raise   # a real kill leaves its tmp dir; the injected one must
         except Exception:
@@ -697,8 +705,9 @@ class ArtifactStore:
             raise
         self._fault("published", name, path=final)
         import jax.numpy as jnp
-        return Table({n: jnp.asarray(a) for n, a in packed.items()},
-                     jnp.asarray(valid))
+        with obs.span("restore.store.flush.upload"):
+            return Table({n: jnp.asarray(a) for n, a in packed.items()},
+                         jnp.asarray(valid))
 
     def _publish(self, tmp: str, final: str):
         """Atomically swap ``tmp`` into place.  An existing version is
@@ -720,11 +729,12 @@ class ArtifactStore:
         n_parts, shard_cap = part["n_parts"], part["shard_capacity"]
         if pid is None:     # write_behind=False path recomputes inline
             pid = _partition_ids(table, part["keys"], n_parts)
-        mask = np.asarray(table.valid).astype(bool)
-        host = {n: np.asarray(c) for n, c in table.columns.items()}
-        blocks, counts = _slice_partitions(host, mask, pid, n_parts,
-                                           shard_cap)
-        vblocks = [np.arange(shard_cap) < c for c in counts]
+        with obs.span("restore.store.flush.fetch"):
+            host, mask = table.to_host()
+        with obs.span("restore.store.flush.compact"):
+            blocks, counts = _slice_partitions(host, mask, pid, n_parts,
+                                               shard_cap)
+            vblocks = [np.arange(shard_cap) < c for c in counts]
         final = self._path(name)
         self._fault("write", name)
         tmp = tempfile.mkdtemp(dir=self.root, prefix=".tmp-")
@@ -732,17 +742,20 @@ class ArtifactStore:
             checks = {}
             for p in range(n_parts):
                 fn = f"shard_{p:05d}.npz"
-                data = _npz_bytes(dict(
-                    __valid__=vblocks[p],
-                    **{n: blocks[n][p] for n in host}))
-                checks[fn] = zlib.crc32(data)
-                with open(os.path.join(tmp, fn), "wb") as f:
-                    f.write(data)
+                with obs.span("restore.store.flush.encode"):
+                    data = _npz_bytes(dict(
+                        __valid__=vblocks[p],
+                        **{n: blocks[n][p] for n in host}))
+                    checks[fn] = zlib.crc32(data)
+                with obs.span("restore.store.flush.write"):
+                    with open(os.path.join(tmp, fn), "wb") as f:
+                        f.write(data)
             meta["checksums"] = checks
-            with open(os.path.join(tmp, "manifest.json"), "w") as f:
-                json.dump(meta, f)
-            self._fault("publish", name, path=tmp)
-            self._publish(tmp, final)
+            with obs.span("restore.store.flush.write"):
+                with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                    json.dump(meta, f)
+                self._fault("publish", name, path=tmp)
+                self._publish(tmp, final)
         except SimulatedCrash:
             raise
         except Exception:
@@ -750,9 +763,10 @@ class ArtifactStore:
             raise
         self._fault("published", name, path=final)
         import jax.numpy as jnp
-        return Table({n: jnp.asarray(np.concatenate(bs))
-                      for n, bs in blocks.items()},
-                     jnp.asarray(np.concatenate(vblocks)))
+        with obs.span("restore.store.flush.upload"):
+            return Table({n: jnp.asarray(np.concatenate(bs))
+                          for n, bs in blocks.items()},
+                         jnp.asarray(np.concatenate(vblocks)))
 
     # ------------------------------------------------------------------ api
     def exists(self, name: str) -> bool:
@@ -1128,8 +1142,9 @@ class ArtifactStore:
         # one read of the (already synchronized) validity mask — a
         # zero-copy view on CPU, one small transfer on TPU — plus, for
         # partitioned artifacts, one pass of the partition hash.
-        valid_mask = np.asarray(table.valid).astype(bool)
-        nvalid = int(valid_mask.sum())
+        with obs.span("restore.store.put.mask"):
+            valid_mask = np.asarray(table.valid).astype(bool)
+            nvalid = int(valid_mask.sum())
         pid = None
         if partitioning is not None:
             if hasattr(partitioning, "to_dict"):
@@ -1635,7 +1650,8 @@ class ArtifactStore:
         """Durability barrier: returns once every accepted put() has been
         atomically published to disk (no-op for the memory backend)."""
         if self._wb is not None:
-            self._wb.flush()
+            with obs.span("restore.store.flush.wait"):
+                self._wb.flush()
 
     def close(self):
         if self._wb is not None:
