@@ -466,7 +466,7 @@ def execute_plan(plan: PhysicalPlan, datasets: Dict[str, Table],
                  lossless: bool = False):
     """Evaluate a physical plan.  Returns (outputs, stats):
     outputs: store-name -> output Table (uncompacted; the artifact
-    store compacts host-side on its write path);
+    store's flusher compacts it on the device before writing it);
     stats: op uid -> dict of traced scalars (rows_out, join_overflow,
     shuffle_overflow).
 

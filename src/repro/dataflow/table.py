@@ -6,8 +6,9 @@ is a struct-of-arrays with a *compile-time capacity* and a validity mask:
   * every column is a jnp array of shape ``(capacity,)`` (numeric) or
     ``(capacity, width)`` (fixed-width byte strings, dtype uint8);
   * ``valid`` is a boolean ``(capacity,)`` mask — Filter marks rows
-    invalid instead of compacting; compaction happens host-side on the
-    artifact store's write path (see ``host_compact``).
+    invalid instead of compacting; the artifact store's flusher compacts
+    an output on the device (``Table.compact``) before it copies the
+    stored rows to the host.
 
 Tables are pytrees so they flow through jit/shard_map unchanged.
 """
@@ -142,18 +143,25 @@ class Table:
     def select(self, names) -> "Table":
         return Table({n: self.columns[n] for n in names}, self.valid)
 
-    def compact(self) -> "Table":
-        """Reorder rows so valid rows form a prefix (stable).
-
-        Device-side utility (the artifact store compacts host-side via
-        ``host_compact`` instead).  Sort-free: ``order[j]`` = index of
-        the j-th valid row, found by binary-searching the running count
-        of valid rows — XLA's CPU sort is ~5x slower than
-        cumsum+searchsorted+gather at these sizes."""
-        cnt = jnp.cumsum(self.valid.astype(jnp.int32))
-        order = jnp.searchsorted(cnt, jnp.arange(1, self.capacity + 1))
-        order = jnp.clip(order, 0, self.capacity - 1)
-        return self.gather(order, jnp.arange(self.capacity) < cnt[-1])
+    def compact(self, capacity: int | None = None) -> "Table":
+        """Valid rows first, in stable order, then zero-filled invalid
+        rows, at the static ``capacity`` (this table's by default; valid
+        rows past it are dropped).  The artifact store's flusher runs it
+        jitted (``compact_to``) before the copy to the host.  The order
+        is a stable argsort of the invalid flags, and the rows move in
+        one gather of their byte-packed form (``pack_rows``): on a TPU
+        v5e, at 2^22 rows, the gathers dominate, and one gather of packed
+        rows took under half the time of a gather per column, while a
+        binary search or a scatter over the running count of valid rows
+        took longer than the sort."""
+        cap = self.capacity if capacity is None else int(capacity)
+        order = jnp.argsort(~self.valid, stable=True)[:cap]
+        # past this table's capacity, rows gather row 0 and are zeroed
+        order = jnp.pad(order, (0, cap - order.shape[0]))
+        keep = jnp.arange(cap) < jnp.sum(self.valid.astype(jnp.int32))
+        packed, layout = pack_rows(self.columns, self.valid)
+        rows = jnp.where(keep[:, None], jnp.take(packed, order, axis=0), 0)
+        return Table(unpack_rows(rows, layout)[0], keep)
 
     def to_host(self) -> "Tuple[Dict[str, np.ndarray], np.ndarray]":
         """Every column and the validity mask, copied to the host."""
@@ -161,22 +169,8 @@ class Table:
                 np.asarray(self.valid).astype(bool))
 
 
-def host_compact(cols: Dict[str, np.ndarray], mask: np.ndarray,
-                 capacity: int, nvalid: int) -> Dict[str, np.ndarray]:
-    """Numpy-side compaction for the store's write path, of columns and
-    mask already on the host (``Table.to_host``): extract the ``nvalid``
-    valid rows (stable), pad to ``capacity``.  Returns column arrays plus
-    ``__valid__``; runs off the device and off the timed path (flusher
-    thread)."""
-    out: Dict[str, np.ndarray] = {}
-    for n, a in cols.items():
-        a = a[mask][:capacity]
-        if len(a) < capacity:
-            pad = [(0, capacity - len(a))] + [(0, 0)] * (a.ndim - 1)
-            a = np.pad(a, pad)
-        out[n] = a
-    out["__valid__"] = np.arange(capacity) < nvalid
-    return out
+# one program per schema, input capacity and output capacity
+compact_to = jax.jit(Table.compact, static_argnames=("capacity",))
 
 
 def concat_tables(parts, capacity: int | None = None) -> Table:
@@ -259,13 +253,12 @@ def decode_strings(arr: np.ndarray):
 
 
 def _col_bytes(c: jnp.ndarray) -> jnp.ndarray:
-    if c.ndim == 2:                      # fixed-width string: already bytes
-        return c
+    """A column's rows as bytes: ``(N, ...)`` of any dtype -> ``(N, B)``."""
     if c.dtype == jnp.bool_:
-        return c.astype(jnp.uint8)[:, None]
-    if c.dtype == jnp.uint8:
-        return c[:, None]
-    return jax.lax.bitcast_convert_type(c, jnp.uint8)   # (N,) -> (N, itemsize)
+        c = c.astype(jnp.uint8)
+    if c.dtype != jnp.uint8:
+        c = jax.lax.bitcast_convert_type(c, jnp.uint8)  # adds an itemsize axis
+    return c.reshape(c.shape[0], -1)
 
 
 def pack_rows(cols: Dict[str, jnp.ndarray], valid: jnp.ndarray
@@ -278,7 +271,7 @@ def pack_rows(cols: Dict[str, jnp.ndarray], valid: jnp.ndarray
         c = cols[n]
         b = _col_bytes(c)
         parts.append(b)
-        layout.append((n, str(c.dtype), int(b.shape[1]), c.ndim == 2))
+        layout.append((n, str(c.dtype), int(b.shape[1]), tuple(c.shape[1:])))
     parts.append(valid.astype(jnp.uint8)[:, None])
     return jnp.concatenate(parts, axis=1), tuple(layout)
 
@@ -289,17 +282,16 @@ def unpack_rows(packed: jnp.ndarray, layout: Tuple
     unpack to zero values with valid=False."""
     cols: Dict[str, jnp.ndarray] = {}
     off = 0
-    for name, dtype, width, is_string in layout:
+    for name, dtype, width, tail in layout:
         b = packed[:, off:off + width]
         off += width
-        if is_string:
-            cols[name] = b
-        elif dtype == "bool":
-            cols[name] = b[:, 0].astype(jnp.bool_)
-        elif dtype == "uint8":
-            cols[name] = b[:, 0]
-        else:
-            cols[name] = jax.lax.bitcast_convert_type(b, jnp.dtype(dtype))
+        dt = jnp.dtype(dtype)
+        if dt == jnp.bool_:
+            b = b.astype(jnp.bool_)
+        elif dt != jnp.uint8:
+            b = jax.lax.bitcast_convert_type(
+                b.reshape(b.shape[0], -1, dt.itemsize), dt)
+        cols[name] = b.reshape(b.shape[:1] + tail)
     valid = packed[:, off].astype(jnp.bool_)
     return cols, valid
 

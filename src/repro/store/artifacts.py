@@ -13,11 +13,12 @@ content-addressed names.  Storage hierarchy (DESIGN.md §3):
     ``manifest.json`` (schema, capacity, row count, byte size, creation
     time).  Writes are **write-behind**: ``put()`` records metadata and
     caches the table synchronously, then a background flusher thread
-    performs the device→host transfer and ``np.savez`` off the timed
-    path.  Publication stays atomic (tmp dir + rename), so a killed
-    writer never leaves a torn artifact — the fault-tolerance contract
-    the checkpoint layer relies on.  ``flush()`` is the durability
-    barrier: after it returns every accepted ``put`` is on disk.
+    compacts it on the device, copies the stored rows to the host and
+    runs ``np.savez`` off the timed path.  Publication stays atomic (tmp
+    dir + rename), so a killed writer never leaves a torn artifact — the
+    fault-tolerance contract the checkpoint layer relies on.  ``flush()``
+    is the durability barrier: after it returns every accepted ``put`` is
+    on disk.
 
 Repeated ``put``s of the same name coalesce in the write queue (only the
 newest version is flushed), so benchmark loops that re-store an artifact
@@ -40,7 +41,7 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from .. import obs
-from ..dataflow.table import (Table, concat_tables, host_compact,
+from ..dataflow.table import (Table, compact_to, concat_tables,
                               partition_ids_device, slice_valid)
 
 # Default byte bound for the device-resident cache tier.
@@ -333,7 +334,8 @@ class DeviceCache:
 class _WriteBehind:
     """Background flusher: bounded, coalescing queue of pending artifact
     writes.  The caller thread enqueues (table, meta); this thread does
-    device→host transfer + np.savez + atomic rename."""
+    the device-side compaction, the device→host transfer of the stored
+    rows, np.savez and the atomic rename."""
 
     def __init__(self, store: "ArtifactStore", max_depth: int):
         self._store = store
@@ -478,9 +480,10 @@ class _WriteBehind:
                     del self._jobs[name]     # no newer put superseded us
                     if compacted is not None:
                         self.failures.pop(name, None)   # healed
-                        # swap the compacted table into the device cache
-                        # so reuse paths see the truncated capacity —
-                        # unless a newer put already cached fresher data
+                        # swap the compacted table (on the device) into
+                        # the device cache so reuse paths see the
+                        # truncated capacity — unless a newer put
+                        # already cached fresher data
                         self._store.cache.swap_if(name, job[0], compacted,
                                                   job[1]["nbytes"])
                     elif err is not None:
@@ -553,7 +556,10 @@ class ArtifactStore:
                     "memload_bytes": 0, "memload_s": 0.0,
                     "hostload_bytes": 0, "hostload_s": 0.0,
                     "remoteload_bytes": 0, "remoteload_s": 0.0,
-                    "store_bytes": 0, "store_s": 0.0}
+                    "store_bytes": 0, "store_s": 0.0,
+                    # what the flusher copied to the host for unsharded
+                    # writes, against the live tables it was handed
+                    "flush_fetch_bytes": 0, "flush_live_bytes": 0}
         self.cache = DeviceCache(cache_bytes)
         self.cache.on_evict = self._on_device_evict
         # pinned-host tier: numpy payloads demoted from device (§15)
@@ -659,11 +665,12 @@ class ArtifactStore:
 
     def _write_to_disk(self, name: str, table: Table, meta: dict,
                        pid=None) -> Table:
-        """Compact host-side, serialize, atomically publish one artifact.
-        Runs on the flusher thread (write-behind) or inline
-        (write_behind=False); either way a crash mid-write leaves only an
-        unpublished tmp dir, never a torn artifact.  Returns the
-        compacted table (numpy-backed) for the device-cache swap.
+        """Compact on the device, copy the stored rows to the host,
+        serialize, atomically publish one artifact.  Runs on the flusher
+        thread (write-behind) or inline (write_behind=False); either way a
+        crash mid-write leaves only an unpublished tmp dir, never a torn
+        artifact.  Returns the compacted device table for the
+        device-cache swap.
 
         Partitioned artifacts (``meta["partitioning"]``) are written as
         one ``shard_%05d.npz`` file per partition — each shard compacted
@@ -674,13 +681,18 @@ class ArtifactStore:
         part = meta.get("partitioning")
         if part is not None:
             return self._write_sharded(name, table, meta, pid)
-        with obs.span("restore.store.flush.fetch"):
-            host, mask = table.to_host()
+        self._io["flush_live_bytes"] += table.nbytes()
         with obs.span("restore.store.flush.compact"):
-            packed = host_compact(host, mask, meta["capacity"],
-                                  meta["rows"])
-        del host, mask  # the uncompacted copy is not held through the write
-        valid = packed.pop("__valid__")
+            # an all-valid table is already compact
+            if meta["rows"] != table.capacity:
+                out = compact_to(table, capacity=meta["capacity"])
+                # the jitted program returns columns in sorted order; the
+                # file keeps the live table's
+                table = Table({n: out.columns[n] for n in table.columns},
+                              out.valid.block_until_ready())
+        with obs.span("restore.store.flush.fetch"):
+            packed, valid = table.to_host()
+        self._io["flush_fetch_bytes"] += table.nbytes()
         final = self._path(name)
         self._fault("write", name)
         tmp = tempfile.mkdtemp(dir=self.root, prefix=".tmp-")
@@ -704,10 +716,9 @@ class ArtifactStore:
             shutil.rmtree(tmp, ignore_errors=True)
             raise
         self._fault("published", name, path=final)
-        import jax.numpy as jnp
+        # the compacted table is on the device already: nothing to upload
         with obs.span("restore.store.flush.upload"):
-            return Table({n: jnp.asarray(a) for n, a in packed.items()},
-                         jnp.asarray(valid))
+            return table
 
     def _publish(self, tmp: str, final: str):
         """Atomically swap ``tmp`` into place.  An existing version is
@@ -1137,8 +1148,8 @@ class ArtifactStore:
         # Stored artifacts shrink to the live row count (next power of 2):
         # this is what makes reusing a selective Filter/Project output
         # cheaper than recomputing it (paper Figs 16/17) — a stored HDFS
-        # file is only as big as its rows.  The compaction itself happens
-        # host-side on the flusher thread; the only on-clock work here is
+        # file is only as big as its rows.  The flusher thread compacts
+        # on the device before its copy; the only on-clock work here is
         # one read of the (already synchronized) validity mask — a
         # zero-copy view on CPU, one small transfer on TPU — plus, for
         # partitioned artifacts, one pass of the partition hash.

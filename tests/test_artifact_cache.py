@@ -343,3 +343,71 @@ def test_manifest_capacity_matches_stored_data(tmp_path):
     assert store2.get("x").capacity == manifest["capacity"]
     store.close()
     store2.close()
+
+
+def _npz_of_numpy_path(t: Table, capacity: int, nvalid: int) -> dict:
+    """The arrays the host-side write path stored: every column fetched
+    whole, its valid rows kept in order, zero-padded to ``capacity``."""
+    mask = np.asarray(t.valid).astype(bool)
+    out = {"__valid__": np.arange(capacity) < nvalid}
+    for n, c in t.columns.items():
+        a = np.asarray(c)[mask][:capacity]
+        out[n] = np.pad(a, [(0, capacity - len(a))] + [(0, 0)] * (a.ndim - 1))
+    return out
+
+
+@pytest.mark.parametrize("write_behind", [True, False])
+def test_device_compaction_writes_the_numpy_paths_files(
+        tmp_path, monkeypatch, write_behind):
+    """The flusher compacts on the device and fetches only the stored
+    rows; the files, the manifest's crc32, the swapped cache entry and
+    the byte counters are what the host-side compaction gave."""
+    import time
+    import zlib
+
+    import jax.numpy as jnp
+
+    from repro.store.artifacts import _npz_bytes
+    # npz members carry the time they were written: hold it still
+    fixed = time.localtime(1_600_000_000)
+    monkeypatch.setattr(time, "localtime", lambda *a: fixed)
+    rng = np.random.default_rng(7)
+    n = 100
+    mask = np.zeros(n, bool)
+    mask[rng.choice(n, 37, replace=False)] = True
+    cols = {"v": rng.standard_normal(n).astype(np.float32),   # not sorted
+            "s": rng.integers(0, 256, (n, 20), dtype=np.uint8),
+            "k": rng.integers(-1000, 1000, n).astype(np.int32)}
+    part = Table(Table.from_numpy(cols).columns, jnp.asarray(mask))
+    full = Table.from_numpy(cols)
+    store = ArtifactStore(root=str(tmp_path / "a"), write_behind=write_behind)
+    for name, t, cap in (("part", part, 64), ("full", full, n)):
+        io0 = store.io_stats()
+        meta = store.put(name, t)
+        store.flush()
+        assert meta["capacity"] == cap
+        want = _npz_of_numpy_path(t, cap, meta["rows"])
+        with open(os.path.join(store._path(name), "data.npz"), "rb") as f:
+            data = f.read()
+        assert data == _npz_bytes(want)
+        with open(os.path.join(store._path(name), "manifest.json")) as f:
+            crc = json.load(f)["checksums"]["data.npz"]
+        assert crc == zlib.crc32(data)
+        z = np.load(os.path.join(store._path(name), "data.npz"))
+        assert list(z.files) == list(want)
+        for k in want:
+            assert z[k].dtype == want[k].dtype
+            np.testing.assert_array_equal(z[k], want[k])
+        cached = store.cache.get(name)
+        assert cached.capacity == cap
+        if name == "full":        # handed on, not copied
+            assert cached is t
+        io = store.io_stats()
+        assert io["flush_live_bytes"] - io0["flush_live_bytes"] == t.nbytes()
+        assert (io["flush_fetch_bytes"] - io0["flush_fetch_bytes"]
+                == meta["nbytes"] == cached.nbytes())
+        store.drop_caches()
+        got = store.get(name).to_numpy()
+        for k, c in t.to_numpy().items():
+            np.testing.assert_array_equal(got[k], c)
+    store.close()

@@ -7,6 +7,7 @@ multiples.
 """
 import jax
 import numpy as np
+import pytest
 
 from repro.core import plan as P
 from repro.dataflow import physical as PH
@@ -121,8 +122,8 @@ def test_pallas_padded_matches_fallback_at_odd_capacity():
 
 
 def test_compact_is_stable_and_sort_free_correct():
-    """Table.compact() (cumsum+searchsorted, no sort) must move valid
-    rows to a prefix preserving their order."""
+    """Table.compact() must move valid rows to a prefix preserving
+    their order."""
     rng = np.random.default_rng(11)
     n = 513                              # deliberately not a power of two
     v = np.zeros(n, bool)
@@ -134,6 +135,46 @@ def test_compact_is_stable_and_sort_free_correct():
     assert got_valid[:200].all() and not got_valid[200:].any()
     np.testing.assert_array_equal(np.asarray(c.col("a"))[:200],
                                   np.flatnonzero(v).astype(np.int32))
+
+
+def _masks(n=100):
+    scattered = np.zeros(n, bool)
+    scattered[np.random.default_rng(4).choice(n, 41, replace=False)] = True
+    first, last, pow2 = (np.zeros(n, bool) for _ in range(3))
+    first[0] = last[-1] = True
+    pow2[np.random.default_rng(5).choice(n, 32, replace=False)] = True
+    return {"all": np.ones(n, bool), "none": np.zeros(n, bool),
+            "scattered": scattered, "first": first, "last": last,
+            "pow2": pow2}
+
+
+@pytest.mark.parametrize("mask", sorted(_masks()))
+def test_compact_to_static_capacity_matches_numpy(mask):
+    """``compact_to`` at the store's capacity (power of two over the
+    valid rows, at least 8, at most the table's) equals the host-side
+    compaction bit for bit: valid rows in order, then zero rows, for
+    every column shape and dtype a Table holds."""
+    from repro.dataflow.table import compact_to
+    n = 100
+    v = _masks(n)[mask]
+    rng = np.random.default_rng(13)
+    f = rng.standard_normal(n).astype(np.float32)
+    f[:4] = [-0.0, np.nan, 1e-40, -np.inf]       # bits, not values
+    cols = {"i": rng.integers(-2**31, 2**31 - 1, n, dtype=np.int32),
+            "f": f, "s": rng.integers(0, 256, (n, 20), dtype=np.uint8),
+            # token rows (train/data.py) and flags
+            "t": rng.integers(-2**31, 2**31 - 1, (n, 3), dtype=np.int32),
+            "b": rng.random(n) < 0.5}
+    nvalid = int(v.sum())
+    cap = min(n, max(8, 1 << (max(nvalid, 1) - 1).bit_length()))
+    t = Table(Table.from_numpy(cols).columns, jax.numpy.asarray(v))
+    got, got_valid = compact_to(t, capacity=cap).to_host()
+    np.testing.assert_array_equal(got_valid, np.arange(cap) < nvalid)
+    for k, a in cols.items():
+        a = a[v][:cap]
+        want = np.pad(a, [(0, cap - len(a))] + [(0, 0)] * (a.ndim - 1))
+        assert got[k].dtype == want.dtype and got[k].shape == want.shape
+        assert got[k].tobytes() == want.tobytes(), k
 
 
 def test_hash_cache_shares_across_operators():
