@@ -16,6 +16,13 @@ terms, addresses, the opaque columns) and the order of the rows.  So
 group, join and filter sizes, and the bytes the store holds, do not
 change with the seed, while the bytes the program hashes, sorts and
 matches do.
+
+A mix that churns a base table (``loadgen``'s ``churn``) re-registers it
+with the table's further data sets.  Data set 0 of ``page_views`` is the
+one ``tables`` gives; data set ``d`` >= 1 keeps the user and query-term
+rank columns, the names and terms and the power set, so ``users``,
+``power_users``, group counts and join sizes stay as they are, and draws
+every other column and the order of the rows afresh from ``[seed, 1, d]``.
 """
 from __future__ import annotations
 
@@ -58,6 +65,13 @@ def _ranks(data: dict) -> dict:
         "n_users": len(uc), "n_terms": len(tc),
         "power": np.sort(rng.choice(len(uc), data["power_users_rows"],
                                     replace=False)),
+        **_values(rng, n),
+    }
+
+
+def _values(rng, n: int) -> dict:
+    """Each row's action, time spent, hour and revenue."""
+    return {
         "action": rng.integers(1, 3, n).astype(np.int32),
         "timespent": rng.integers(0, 100, n).astype(np.int32),
         "timestamp": rng.integers(0, 24, n).astype(np.int32),
@@ -77,29 +91,59 @@ def _people(rng, names: np.ndarray, w: dict) -> dict:
     }
 
 
-def tables(data: dict, seed: int) -> dict:
-    """The three PigMix tables of a configuration, as host columns."""
-    r = _ranks(data)
+def _keys(data: dict, r: dict, seed: int):
+    """The run seed's generator, after it drew the user names and the
+    query terms, and those."""
     w = data["widths"]
     rng = np.random.default_rng([seed, 1])
     names = random_strings(rng, r["n_users"], w["user"], unique=True)
     terms = random_strings(rng, r["n_terms"], w["query_term"], unique=True)
+    return rng, names, terms
+
+
+def _page_views(data: dict, r: dict, names, terms, rng, values: dict):
+    w = data["widths"]
     n = data["page_views_rows"]
     order = rng.permutation(n)
-    pv = {
+    return {
         "user": names[r["user"][order]],
-        "action": r["action"][order],
-        "timespent": r["timespent"][order],
+        "action": values["action"][order],
+        "timespent": values["timespent"][order],
         "query_term": terms[r["query_term"][order]],
         "ip_addr": rng.integers(0, 256, (n, w["ip_addr"]), dtype=np.uint8),
-        "timestamp": r["timestamp"][order],
-        "estimated_revenue": r["estimated_revenue"][order],
+        "timestamp": values["timestamp"][order],
+        "estimated_revenue": values["estimated_revenue"][order],
         "page_info": random_strings(rng, n, w["page_info"]),
         "page_links": random_strings(rng, n, w["page_links"]),
     }
+
+
+def tables(data: dict, seed: int) -> dict:
+    """The three PigMix tables of a configuration, as host columns."""
+    r = _ranks(data)
+    w = data["widths"]
+    rng, names, terms = _keys(data, r, seed)
+    pv = _page_views(data, r, names, terms, rng, r)
     users = _people(rng, names, w)
     power = _people(rng, names[r["power"]], w)
     return {"page_views": pv, "users": users, "power_users": power}
+
+
+def page_views(data: dict, seed: int, d: int) -> dict:
+    """Data set ``d`` of ``page_views``: the same users and terms with
+    the same row counts each, all other columns and the row order drawn
+    from ``[seed, 1, d]``; data set 0 is that of ``tables``."""
+    if d == 0:
+        return tables(data, seed)["page_views"]
+    r = _ranks(data)
+    _, names, terms = _keys(data, r, seed)
+    rng = np.random.default_rng([seed, 1, d])
+    return _page_views(data, r, names, terms, rng,
+                       _values(rng, data["page_views_rows"]))
+
+
+# the base tables a mix may churn, each with its data sets
+DATA_SETS = {"page_views": page_views}
 
 
 def row_bytes(cols: dict) -> int:

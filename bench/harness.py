@@ -11,12 +11,19 @@ A query is timed from ``submit`` until its answer's tables are ready on
 the device and the client holds the answer's row count, which a device
 reduction over the answer's validity mask gives.  Reading answers to the
 host for the check happens after the window.
+
+A mix may churn a base table (``Churn``): a loader thread re-registers
+it with its next data set on a fixed clock while the clients run, and
+each answer is checked against the data of the versions it could have
+read.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import math
 import os
+import resource
 import shutil
 import sys
 import tempfile
@@ -159,12 +166,12 @@ def _manifest_dirs(root: str) -> dict:
     return out
 
 
-def hold_on_disk(root: str, artifact: str, dest: str) -> bool:
+def hold_on_disk(root: str, artifact: str, dest: str, dirs: dict) -> bool:
     """Hard-link the files of ``artifact`` as they are on disk now into
     ``dest`` (a store root of its own); False where it is not on disk.
     A link costs no copy, and later deletes or rewrites of the store's
-    files leave it as it was."""
-    d = _manifest_dirs(root).get(artifact)
+    files leave it as it was.  ``dirs`` is ``_manifest_dirs(root)``."""
+    d = dirs.get(artifact)
     if d is None:
         return False
     src, dst = os.path.join(root, d), os.path.join(dest, d)
@@ -178,12 +185,16 @@ def hold_on_disk(root: str, artifact: str, dest: str) -> bool:
 
 
 class Sampler:
-    """The answers kept for the check.  Answers that are the very arrays
-    of one already kept (whole-job reuse serves the stored artifact) are
-    covered by it; of the others, ``keep`` per template are kept, drawn
-    from the seed by reservoir sampling.  Each kept answer's artifact is
-    held as it was on disk when the answer returned (``hold_on_disk``),
-    for the durability check after the window."""
+    """The answers kept for the check, by key: ``(template, data sets the
+    answer may have been computed on, data sets only older versions
+    had)`` (``Client.answer_key``).  Answers that are the very arrays of
+    one already kept under the key (whole-job reuse serves the stored
+    artifact) are covered by it; of the others, ``keep`` per key are
+    kept, drawn from the seed by reservoir sampling.  The artifacts that
+    may hold each kept answer are held as they were on disk when the
+    answer returned (``hold_on_disk``), for the durability check after
+    the window, each with whether it is the output of a version the
+    answer could have read (``Client.held_names``)."""
 
     def __init__(self, seed: int, keep: int, store_root: str,
                  held_root: str):
@@ -191,50 +202,56 @@ class Sampler:
         self.keep = keep
         self.store_root, self.held_root = store_root, held_root
         self.lock = threading.Lock()
-        self.kept: dict = {}   # template -> [[table, covered, held], ...]
+        self.kept: dict = {}   # key -> [[table, covered, held], ...]
         self.distinct: dict = {}
         self.offered = 0
         self._slots = 0
 
-    def _hold(self, artifact: str):
-        """(slot directory, artifact) or (None, artifact) where the
-        artifact was not on disk."""
+    def _hold(self, artifacts: list):
+        """(slot directory, or None where none was on disk; ``(artifact,
+        in_range, held)`` for each of ``artifacts``)."""
         self._slots += 1
         dest = os.path.join(self.held_root, str(self._slots))
         os.makedirs(dest)
-        if hold_on_disk(self.store_root, artifact, dest):
-            return dest, artifact
-        return None, artifact
+        dirs = _manifest_dirs(self.store_root)
+        arts = [(a, in_range, hold_on_disk(self.store_root, a, dest, dirs))
+                for a, in_range in artifacts]
+        return (dest if any(h for *_, h in arts) else None), arts
 
-    def offer(self, name: str, table, artifact: str) -> None:
+    def offer(self, key: tuple, table, artifacts) -> None:
+        """``artifacts()`` lists ``(artifact, in_range)`` for the
+        artifacts that may hold the answer, resolved through the store's
+        aliases."""
         with self.lock:
             self.offered += 1
-            kept = self.kept.setdefault(name, [])
+            kept = self.kept.setdefault(key, [])
             for k in kept:
                 if k[0].valid is table.valid:
                     k[1] += 1
                     return
-            n = self.distinct[name] = self.distinct.get(name, 0) + 1
+            n = self.distinct[key] = self.distinct.get(key, 0) + 1
             if len(kept) < self.keep:
-                kept.append([table, 0, self._hold(artifact)])
+                kept.append([table, 0, self._hold(artifacts())])
             else:
                 j = int(self.rng.integers(n))
                 if j < self.keep:
                     old = kept[j][2][0]
                     if old:
                         shutil.rmtree(old, ignore_errors=True)
-                    kept[j] = [table, 0, self._hold(artifact)]
+                    kept[j] = [table, 0, self._hold(artifacts())]
 
 
 class Query:
     __slots__ = ("template", "client", "t_submit", "t_done", "latency_s",
-                 "n_executed", "n_reused", "spans", "error")
+                 "n_executed", "n_reused", "spans", "error", "versions")
 
     def __init__(self, template, client):
         self.template, self.client = template, client
         self.error = None
         self.spans = {}
         self.n_executed = self.n_reused = 0
+        # the churned table's versions at submit and at return
+        self.versions = None
 
 
 class Run:
@@ -271,6 +288,89 @@ def _resolve(store, name: str) -> str:
     return name
 
 
+class Churn:
+    """A mix's base-table churn (``loadgen``'s ``churn``).  A loader
+    thread re-registers the table in the service's catalog with its next
+    data set every ``every_s`` seconds of the window, on the window's
+    clock, cycling through the data sets, whose host columns set-up
+    made; the upload is the loader's.  It uses the system's ingest call
+    alone: no eviction, no maintenance.  Keeping answers fresh is the
+    program's work.
+
+    ``done`` is the table's newest catalog version whose registration
+    had returned, ``started`` the newest whose registration had begun:
+    a query that read ``done`` at its submit and ``started`` at its
+    return may have been computed on any version between them."""
+
+    def __init__(self, spec: dict, svc, sets: list):
+        self.table = spec["table"]
+        self.every = int(spec["every_s"])
+        self.svc, self.sets = svc, sets
+        self.first = self.started = self.done = svc.catalog.version(
+            self.table)
+        self.applied = 0          # in the window
+        self.seconds = []         # each churn's upload and registration
+        self.error = None
+        self._stop = threading.Event()
+        self._thread = None
+
+    def data_set(self, version: int) -> int:
+        return (version - self.first) % len(self.sets)
+
+    def data_sets(self, v_submit: int, v_done: int) -> tuple:
+        """(the data sets of versions ``v_submit`` to ``v_done``, those
+        of older versions only), each sorted."""
+        fresh = {self.data_set(v) for v in range(v_submit, v_done + 1)}
+        older = {self.data_set(v) for v in range(
+            max(self.first, v_submit - len(self.sets)), v_submit)}
+        return tuple(sorted(fresh)), tuple(sorted(older - fresh))
+
+    def last_version(self, seconds: float) -> int:
+        """The newest version a window of ``seconds`` can reach from the
+        current one."""
+        return self.done + math.ceil(seconds / self.every) - 1
+
+    def apply(self) -> None:
+        """Registers the table's next data set."""
+        from repro.dataflow.table import Table
+        t0 = time.perf_counter()
+        table = Table.from_numpy(self.sets[self.data_set(self.done + 1)])
+        self.started += 1
+        self.svc.catalog.register(self.table, table)
+        v = self.svc.catalog.version(self.table)
+        if v != self.started:
+            raise RuntimeError(f"{self.table} registered as version {v}, "
+                               f"not {self.started}")
+        self.done = v
+        self.seconds.append(time.perf_counter() - t0)
+
+    def _load(self, t0: float, deadline: float) -> None:
+        try:
+            while True:
+                at = t0 + (self.applied + 1) * self.every
+                if at >= deadline or self._stop.wait(
+                        max(0.0, at - time.perf_counter())):
+                    return
+                self.apply()
+                self.applied += 1
+        except Exception as e:            # reported when it stops
+            self.error = e
+
+    def start(self, t0: float, deadline: float) -> None:
+        """Churns at ``t0 + every_s``, ``t0 + 2 every_s``, ... before
+        ``deadline``; a churn that falls behind its time is applied at
+        once."""
+        self._thread = threading.Thread(target=self._load,
+                                        args=(t0, deadline),
+                                        name="bench-churn")
+        self._thread.start()
+
+    def stop(self) -> None:
+        self._stop.set()
+        if self._thread is not None:
+            self._thread.join()
+
+
 class Client:
     """Asks the service one query at a time and waits for the answer;
     the traffic's loop driver (``loops/<loop>.py``) calls ``ask``."""
@@ -280,16 +380,49 @@ class Client:
         self.count_rows = count_rows
         self.delete_outputs = delete_outputs
         self.sampler = None      # set for the window
+        self.churn = None        # set where the mix churns a table
         self._outputs: dict = {}
 
-    def output_of(self, name: str) -> str:
-        """The dataset that holds template ``name``'s answer."""
-        if name not in self._outputs:
+    def output_of(self, name: str, version: int = None) -> str:
+        """The dataset that holds template ``name``'s answer: that of the
+        plan as submitted, or with the churned table's loads stamped with
+        ``version``, as the program names its outputs."""
+        if (name, version) not in self._outputs:
             from queries import OUTPUT, plan
+            from repro.core.plan import rebind_load_versions
             from repro.dataflow.compiler import compile_workflow
-            self._outputs[name] = compile_workflow(
-                plan(name)).final_outputs[OUTPUT[name]]
-        return self._outputs[name]
+            p = plan(name)
+            if version is not None:
+                p = rebind_load_versions(p, {self.churn.table: version})
+            self._outputs[name, version] = compile_workflow(
+                p).final_outputs[OUTPUT[name]]
+        return self._outputs[name, version]
+
+    def answer_key(self, name: str, versions) -> tuple:
+        """(template, data sets the answer may have been computed on,
+        data sets only older versions had)."""
+        if self.churn is None:
+            return name, (0,), ()
+        return (name, *self.churn.data_sets(*versions))
+
+    def held_names(self, name: str, versions) -> list:
+        """``(artifact, in_range)`` for the artifacts that may hold the
+        answer, through the store's aliases: under churn, the outputs of
+        the plan stamped with each version it could have read, newest
+        first, in range; then that of the plan as submitted, in range
+        only where it is one of those.  Names come from ``output_of``'s
+        cache, which set-up fills for every version the window can
+        reach."""
+        names = []
+        if self.churn is not None:
+            v_submit, v_done = versions
+            names = [(self.output_of(name, v), True)
+                     for v in range(v_done, v_submit - 1, -1)]
+        names.append((self.output_of(name), self.churn is None))
+        out: dict = {}
+        for n, in_range in names:
+            out.setdefault(_resolve(self.svc.store, n), in_range)
+        return list(out.items())
 
     def ask(self, name: str, client: int):
         from queries import OUTPUT, plan
@@ -297,11 +430,14 @@ class Client:
         p = plan(name)
         before = set(self.svc.store.names()) if self.delete_outputs else ()
         table = None
+        churn = self.churn
+        v_submit = churn.done if churn else 0
         q.t_submit = time.perf_counter()
         try:
             with self.rec.span("bench.client.query"):
                 ticket = self.svc.submit(p, tenant=f"tenant{client}")
                 results, report = ticket.result(timeout=LATE_S * 5)
+                q.versions = (v_submit, churn.started if churn else 0)
                 table = results[OUTPUT[name]]
                 # the client holds the answer's row count: the tables
                 # are ready, and the device has done the client's work
@@ -313,8 +449,9 @@ class Client:
         q.latency_s = q.t_done - q.t_submit
         q.spans = self.rec.query(p)
         if table is not None and self.sampler is not None:
-            self.sampler.offer(name, table, _resolve(
-                self.svc.store, self.output_of(name)))
+            versions = q.versions
+            self.sampler.offer(self.answer_key(name, versions), table,
+                               lambda: self.held_names(name, versions))
         if self.delete_outputs:
             # the client of a system without reuse removes what the
             # workflow wrote once it has its answer
@@ -341,6 +478,18 @@ def warm_passes(templates):
             (as_written, as_written, templates),
             (compacted, as_written, multi),
             ((True, True), compacted, multi)]
+
+
+def first_order(mix: dict, seed: int) -> list:
+    """The templates in the order client 0's stream first draws them;
+    any it has not drawn in 100,000 draws follow in the mix's order."""
+    from loadgen import client_stream
+    stream, seen = client_stream(mix, seed, 0), {}
+    for _ in range(100_000):
+        if len(seen) == len(mix["templates"]):
+            break
+        seen.setdefault(next(stream), None)
+    return list(dict.fromkeys([*seen, *mix["templates"]]))
 
 
 def _device_tables(host: dict):
@@ -423,71 +572,141 @@ def check_answers(answers: dict, host: dict, limits: dict,
     ``covered`` counting further answers that were the same arrays) with
     the numpy reference over ``host``.  Returns the compared numbers with
     their limits, and how many answers were wrong."""
-    from queries import FLOAT_COLS
-    from reference import Reference, compare
+    from reference import Reference
     ref = reference or Reference(host)
-    wrong, wrong_served, float_err = 0, 0, 0.0
-    for name in sorted(answers):
-        want = ref.answer(name)
-        for cols, covered in answers[name]:
-            why, err = compare(cols, want, FLOAT_COLS.get(name, ()))
+    checks, bad_served, _ = check_versioned(
+        {(name, (0,), ()): kept for name, kept in answers.items()},
+        lambda d: ref, limits, churn=False)
+    return checks, bad_served
+
+
+def check_versioned(answers: dict, reference_of, limits: dict,
+                    churn: bool) -> tuple:
+    """Compare ``answers`` (``(template, fresh, older)`` -> [(host
+    columns, covered), ...]) with the numpy reference over each data set
+    (``reference_of(d)``).  An answer is correct where it equals the
+    reference over one of the ``fresh`` data sets, those of the versions
+    it could have read; stale where it equals only that of an ``older``
+    one; else wrong.  Returns the compared numbers with their limits
+    (``stale_answers`` where ``churn``), how many answers served were
+    wrong or stale, and the ``(key, index)`` of the stale ones."""
+    from queries import FLOAT_COLS
+    from reference import compare
+    limit = limits["float_rel_err"]
+    wants: dict = {}
+
+    def verdict(name, cols, d):
+        if (d, name) not in wants:
+            wants[d, name] = reference_of(d).answer(name)
+        why, err = compare(cols, wants[d, name], FLOAT_COLS.get(name, ()))
+        return why, err, why is None and err <= limit
+
+    wrong = bad_served = 0
+    stale = set()
+    float_err = 0.0
+    for key in sorted(answers):
+        name, fresh, older = key
+        for i, (cols, covered) in enumerate(answers[key]):
+            found = [verdict(name, cols, d) for d in fresh]
+            ok = [f for f in found if f[2]]
+            if ok:
+                float_err = max(float_err, ok[0][1])
+                continue
+            why, err, _ = found[0]
+            bad_served += 1 + covered
+            was = [d for d in older if verdict(name, cols, d)[2]]
+            if was:
+                log(f"check {name}: stale, the answer over data set "
+                    f"{was[0]}, not over {list(fresh)}")
+                stale.add((key, i))
+                continue
             if err is not None:
                 float_err = max(float_err, err)
-            if why is not None or err > limits["float_rel_err"]:
-                log(f"check {name}: {why or f'float error {err!r}'}")
-                wrong += 1
-                wrong_served += 1 + covered
-    checks = {"wrong_answers": {"value": wrong, "limit": 0},
-              "float_rel_err": {"value": float_err,
-                                "limit": limits["float_rel_err"]}}
-    return checks, wrong_served
+            log(f"check {name}: {why or f'float error {err!r}'}")
+            wrong += 1
+    checks = {"wrong_answers": {"value": wrong, "limit": 0}}
+    if churn:
+        checks["stale_answers"] = {"value": len(stale), "limit": 0}
+    checks["float_rel_err"] = {"value": float_err, "limit": limit}
+    return checks, bad_served, stale
 
 
 def _check(run: Run, sampler: Sampler, host: dict, config: dict,
-           durable: int) -> dict:
+           churn: Churn = None) -> dict:
     """The numbers compared, each with its limit."""
-    answers = {name: [(t.to_numpy(), covered) for t, covered, _ in kept]
-               for name, kept in sampler.kept.items()}
-    found, wrong_served = check_answers(answers, host, config["limits"])
+    from reference import Reference
+    answers = {key: [(t.to_numpy(), covered) for t, covered, _ in kept]
+               for key, kept in sampler.kept.items()}
+    refs: dict = {}
+
+    def reference_of(d: int):
+        if d not in refs:
+            refs[d] = Reference(host if churn is None else
+                                dict(host, **{churn.table: churn.sets[d]}))
+        return refs[d]
+
+    t0 = time.perf_counter()
+    found, wrong_served, stale = check_versioned(answers, reference_of,
+                                                 config["limits"],
+                                                 churn is not None)
     run.failed += wrong_served
     log(f"checked {sum(map(len, answers.values()))} answers against the "
         f"numpy reference, covering {sampler.offered} served in the window")
+    if churn is not None:
+        kept = sorted({d for _, fresh, _ in answers for d in fresh})
+        log(f"churn: kept answers could read data sets {kept}; the "
+            f"reference over data sets {sorted(refs)} took "
+            f"{time.perf_counter() - t0:.6f} s")
     checks = {"failed_queries": {"value": sum(q.error is not None for q in
                                               run.queries + run.late),
                                  "limit": 0}}
     checks.update(found)
-    checks["disk_mismatch"] = {"value": durable, "limit": 0}
+    checks["disk_mismatch"] = {"value": _durable_mismatch(sampler, stale),
+                               "limit": 0}
     return checks
 
 
-def _durable_mismatch(sampler: Sampler) -> int:
-    """Kept answers whose artifact was not on disk when the answer
-    returned, or whose files as they were then, read by a store opened
-    afresh on them, differ from what was served."""
+def _held_differs(held: str, art: str, served: dict):
+    """Why the files of ``art`` held under ``held``, read by a store
+    opened afresh on them, are not the ``served`` answer; None where
+    they are."""
     from reference import compare
     from repro.store.artifacts import ArtifactError, ArtifactStore
+    fresh = ArtifactStore(root=held, cache_bytes=0, write_behind=False)
+    try:
+        disk = fresh.get(art).to_numpy()
+    except (ArtifactError, KeyError) as e:
+        return f"{art} unreadable on disk: {e!r}"
+    finally:
+        fresh.close()
+    why, _ = compare(disk, served)
+    return None if why is None else f"{art} on disk: {why}"
+
+
+def _durable_mismatch(sampler: Sampler, stale=frozenset()) -> int:
+    """Kept answers none of whose artifacts was on disk when the answer
+    returned, or whose files as they were then, read by a store opened
+    afresh on them, differ from what was served.  An answer judged fresh
+    has to be in the output of a version it could have read; one in
+    ``stale`` (``(key, index)``, from ``check_versioned``) may be in any
+    artifact held for it."""
     bad = n = 0
-    for name, kept in sorted(sampler.kept.items()):
-        for table, _, (held, art) in kept:
+    for key, kept in sorted(sampler.kept.items()):
+        name = key[0]
+        for i, (table, _, (held, arts)) in enumerate(kept):
             n += 1
-            if held is None:
-                log(f"durability {name}: {art} was not on disk when its "
-                    f"answer returned")
+            if (key, i) not in stale:
+                arts = [a for a in arts if a[1]]
+            on_disk = [a for a, _, h in arts if h]
+            if not on_disk:
+                log(f"durability {name}: none of {[a for a, *_ in arts]} "
+                    f"was on disk when its answer returned")
                 bad += 1
                 continue
-            fresh = ArtifactStore(root=held, cache_bytes=0,
-                                  write_behind=False)
-            try:
-                disk = fresh.get(art).to_numpy()
-            except (ArtifactError, KeyError) as e:
-                log(f"durability {name}: {art} unreadable on disk: {e!r}")
-                bad += 1
-                continue
-            finally:
-                fresh.close()
-            why, _ = compare(disk, table.to_numpy())
-            if why is not None:
-                log(f"durability {name}: {art} on disk: {why}")
+            served = table.to_numpy()
+            why = [_held_differs(held, art, served) for art in on_disk]
+            if all(why):
+                log(f"durability {name}: {'; '.join(why)}")
                 bad += 1
     log(f"durability: read {n} kept answers back from the files on disk "
         f"when they returned, {bad} missing or different")
@@ -523,6 +742,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     from peaks import peaks
     from spans import KERNELS, Recorder, instrument, kernel_bytes
     host = datagen.tables(config, seed)
+    churn_spec = mix.get("churn")
+    sets = None
+    if churn_spec:
+        churned = churn_spec["table"]
+        sets = [host[churned]] + [
+            datagen.DATA_SETS[churned](config, seed, d)
+            for d in range(1, churn_spec["data_sets"])]
     tables = _device_tables(host)
     run = Run()
     run.base_bytes = sum(t.nbytes() for t in tables.values())
@@ -540,7 +766,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     rec = Recorder(annotate=trace)
     undo = instrument(rec)
-    svc = None
+    svc = churn = None
     try:
         svc = make_service(config, tables, root, dev)
         client = Client(svc, rec, _count_rows(),
@@ -557,8 +783,23 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                 if q.error:
                     log(f"set-up {name}: {q.error}")
         rec.job_policy, rec.hold_swaps = None, False
+        if churn_spec:
+            # one churn, then every template after it in the order the
+            # mix first draws them and reversed: the programs a reload
+            # brings are compiled here, not in the window
+            churn = client.churn = Churn(churn_spec, svc, sets)
+            churn.apply()
+            order = first_order(mix, seed)
+            for name in order + order[::-1]:
+                q, _ = client.ask(name, 0)
+                warm_failed += q.error is not None
+                if q.error:
+                    log(f"set-up after a churn {name}: {q.error}")
         for name in mix["templates"]:
             client.output_of(name)
+            if churn is not None:
+                for v in range(churn.first, churn.last_version(seconds) + 1):
+                    client.output_of(name, v)
         n_clients = int(mix["clients"])
         client.sampler = Sampler(seed, int(config["client"]["answers_kept"]),
                                  root, held_root)
@@ -589,12 +830,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         deadline[0] = t0 + seconds
         rec.in_window = compiles.in_window = True
         with rec.span("bench.window"):
+            if churn is not None:
+                churn.start(t0, deadline[0])
             for t in threads:
                 t.start()
             time.sleep(max(0.0, deadline[0] - time.perf_counter()))
         rec.in_window = compiles.in_window = False
         run.window_s = time.perf_counter() - t0
         run.stored_bytes = svc.store.total_bytes()
+        if churn is not None:
+            churn.stop()
+            if churn.error is not None:
+                raise RuntimeError(f"churn of {churn.table}: "
+                                   f"{churn.error!r}") from churn.error
         for t in threads:
             t.join(LATE_S)
         if any(t.is_alive() for t in threads):
@@ -612,11 +860,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         stats = dev.memory_stats() or {}
         peak = stats.get("peak_bytes_in_use")
         log(f"peak_bytes_in_use: {peak}")
+        log(f"peak host RSS: "
+            f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss} KiB")
         log(f"set-up: {compiles.setup['programs']} programs compiled or "
             f"loaded ({compiles.setup['cache_hits']} from the persistent "
             f"cache) in {compiles.setup['seconds']:.6f} s; set-up failures "
             f"{warm_failed}")
         _print_window(run, compiles, n_clients)
+        if churn is not None:
+            log(f"churn: {churn.applied} re-registrations of {churn.table} "
+                f"in the window, {len(churn.seconds) - churn.applied} in "
+                f"set-up; upload and registration median "
+                f"{np.median(churn.seconds) * 1e3:.6f} ms, max "
+                f"{max(churn.seconds) * 1e3:.6f} ms; catalog version "
+                f"{churn.done} at the window's end")
         if trace:
             import trace as trace_mod
             run.trace = trace_mod.reduce(trace_mod.load(trace_dir), KERNELS)
@@ -624,13 +881,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             run.peaks = peaks(dev.device_kind)
         except KeyError:
             run.peaks = None
-        durable = _durable_mismatch(client.sampler)
-        checks = _check(run, client.sampler, host, config, durable)
+        checks = _check(run, client.sampler, host, config, churn)
         checks["failed_queries"]["value"] += warm_failed
         if compiles.window["programs"]:
             log("WARNING: programs compiled inside the window")
         log(f"disk: this process wrote {_written_bytes()} B")
     finally:
+        if churn is not None:
+            churn.stop()
         if svc is not None:
             svc.stop(timeout=LATE_S)
         undo()

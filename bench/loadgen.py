@@ -12,6 +12,13 @@ A mix file holds data only:
                  probability ~ 1/rank**zipf_s, ranks mapped to templates
                  through each client's own permutation
     zipf_s       skew of the zipf order
+    churn        optional: a base table reloaded during the window, as
+                 {"table": "page_views", "every_s": 5, "data_sets": 3}:
+                 every ``every_s`` seconds of the window's clock (a whole
+                 number), the harness registers the whole of ``table``
+                 anew in the service's catalog with its next data set
+                 (``datagen.DATA_SETS``), cycling through ``data_sets``
+                 of them.  The streams do not depend on it.
 
 Every stream is drawn from the run's seed and the client's index, so the
 same seed gives the same sequences.  The zipf order follows the
@@ -28,6 +35,7 @@ import numpy as np
 LOOPS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "loops")
 
 ORDERS = ("rounds", "zipf")
+CHURN_KEYS = {"table", "every_s", "data_sets"}
 
 
 def load_mix(path: str) -> dict:
@@ -41,7 +49,24 @@ def load_mix(path: str) -> dict:
                          f"{ORDERS}")
     if int(mix.get("clients", 0)) < 1 or not mix.get("templates"):
         raise ValueError(f"{path}: needs clients >= 1 and templates")
+    if "churn" in mix:
+        _check_churn(path, mix["churn"])
     return mix
+
+
+def _check_churn(path: str, churn) -> None:
+    from datagen import DATA_SETS
+    if not isinstance(churn, dict) or set(churn) != CHURN_KEYS:
+        raise ValueError(f"{path}: churn needs exactly the keys "
+                         f"{sorted(CHURN_KEYS)}")
+    if churn["table"] not in DATA_SETS:
+        raise ValueError(f"{path}: churn table {churn['table']!r} has no "
+                         f"data sets; one of {sorted(DATA_SETS)}")
+    for key, least in (("every_s", 1), ("data_sets", 2)):
+        v = churn[key]
+        if isinstance(v, bool) or not isinstance(v, int) or v < least:
+            raise ValueError(f"{path}: churn {key} must be a whole "
+                             f"number >= {least}, not {v!r}")
 
 
 def client_stream(mix: dict, seed: int, client: int):

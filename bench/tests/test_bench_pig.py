@@ -21,7 +21,9 @@ def test_rehearsal_is_correct(no_compile_cache, capsys, trace):
         line for line in log.splitlines() if line.startswith(("failed", "set-up "))])
     assert out["attempted"] > 0 and out["failed"] == 0
     assert "compile events inside the window: 0 " in log
-    want = (["store_write_share.pig"]
+    want = (["store_write_share.pig", "flush_fetch_share.pig",
+             "flush_compact_share.pig", "flush_encode_share.pig",
+             "flush_write_share.pig", "engine_sync_share.pig"]
             if trace else ["query_p95_s", "queries_per_s", "setup_s"])
     assert sorted(out["metrics"]) == sorted(want)
     assert list(out)[-1] == "checks"

@@ -21,7 +21,9 @@ def test_rehearsal_is_correct(no_compile_cache, capsys, trace):
     assert out["attempted"] > 0 and out["failed"] == 0
     assert "compile events inside the window: 0 " in log
     want = (["driver_ms_p95.restore", "service_wait_ms_p95.restore",
-             "device_cache_hit_share.restore", "stored_bytes_ratio"]
+             "device_cache_hit_share.restore", "stored_bytes_ratio",
+             "service_queue_ms_p95.restore", "driver_compile_ms_p95.restore",
+             "driver_reuse_ms_p95.restore"]
             if trace else
             ["query_p95_ms.restore", "queries_per_s.restore", "setup_s"])
     assert sorted(out["metrics"]) == sorted(want)
